@@ -159,10 +159,11 @@ def sharded_perf() -> None:
 
     Spawns benchmarks/sharded_perf.py as a child process: the 8-CPU-
     device mesh needs ``--xla_force_host_platform_device_count`` set
-    before jax initializes, which this parent has already done. The
-    child asserts bit-identity against the unsharded engine for every
-    shard count before timing, and (full runs) enforces the >=2x khop
-    speedup at 4 shards itself; compare.py gates the tracked ratio.
+    before jax initializes, so it gets a process of its own, started
+    before this one creates a backend (see ``main``). The child asserts
+    bit-identity against the unsharded engine for every shard count
+    before timing, and (full runs) enforces the >=2x khop speedup at 4
+    shards itself; compare.py gates the tracked ratio.
     """
     import json
     import subprocess
@@ -505,7 +506,7 @@ def traversal_perf() -> None:
     # bit-identity of the frontier compaction step vs its oracle
     cand = _frontier_alters(net, sources[:, None], None, None, node_cap)
     kv, km = kops.frontier_compact(
-        cand, sources[:, None], cap, use_pallas=True, interpret=True
+        cand, sources[:, None], cap, use_pallas=True
     )
     rv, rm = ref.frontier_ref(cand, sources[:, None], cap)
     np.testing.assert_array_equal(np.asarray(kv), np.asarray(rv))
@@ -897,7 +898,7 @@ def kernel_intersect() -> None:
     us = _timeit(jnp_path, aj, bj)
     emit("kernel/intersect_allpairs_jnp", us / B, f"batch={B};K={K}")
     interp = _timeit(
-        lambda x, y: kops.intersect_count(x, y, interpret=True), aj, bj
+        lambda x, y: kops.intersect_count(x, y), aj, bj
     )
     emit("kernel/intersect_pallas_interpret", interp / B,
          "correctness_mode;TPU_is_target")
@@ -948,12 +949,19 @@ def main() -> None:
         SMOKE = True
         N_NODES = min(N_NODES, 5_000)
 
+    from repro.core import compile_cache
+
+    print(f"# compile cache: {compile_cache.enable()}")
     print(f"# benchmark network: {N_NODES:,} nodes "
           f"(BENCH_SCALE={SCALE}, smoke={SMOKE})")
+    # one process per chip: the phases that spawn a JAX child run before
+    # this process creates a backend (importing jax alone claims no
+    # device), so each child can take the accelerator in turn
+    table1_scale()
+    sharded_perf()
     t0 = time.perf_counter()
     net = build_benchmark_network()
     table1_memory(net, build_seconds=time.perf_counter() - t0)
-    table1_scale()
     query_perf(net)
     query_perf_skewed()
     query_perf_filtered()
@@ -961,7 +969,6 @@ def main() -> None:
     serve_perf(net)
     serve_perf_mutating(net)
     serve_slo_perf(net)
-    sharded_perf()
     mutation_churn_perf()
     shortest_path(net)
     walk_throughput(net)

@@ -36,7 +36,7 @@ __all__ = [
     "connected_components",
 ]
 
-_INF = jnp.int32(2**31 - 1)
+_INF = np.int32(2**31 - 1)
 
 
 # ---------------------------------------------------------------------------

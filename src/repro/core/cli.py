@@ -569,6 +569,9 @@ def main() -> None:
     ap.add_argument("script", nargs="?", help="script file (default: stdin)")
     ap.add_argument("--json", action="store_true", help="JSON output mode")
     args = ap.parse_args()
+    from . import compile_cache
+
+    compile_cache.enable()
     text = (
         open(args.script).read() if args.script else sys.stdin.read()
     )

@@ -56,6 +56,8 @@ __all__ = [
     "bucketed_node_alters",
     "bucketed_filtered_degree",
     "alters_bound",
+    "pow2_ceil",
+    "pow2_pad",
     "union_rows",
     "node_max_hyperedge_size",
 ]
@@ -119,11 +121,37 @@ def plan_buckets(
     return out
 
 
-def _pow2_rows(n: int, floor: int = 8) -> int:
+def pow2_ceil(n: int, floor: int = 8) -> int:
+    """Smallest power of two >= ``n`` (and >= ``floor``): padding a
+    data-dependent size to it bounds how many shapes compile."""
     p = floor
     while p < n:
         p <<= 1
     return p
+
+
+def pow2_pad(ids) -> np.ndarray:
+    """``ids`` padded to a power-of-two length with repeats of its first
+    element: a batch then compiles once per size class, and the pad rows
+    answer a real query whose copies the caller drops."""
+    ids = np.asarray(ids)
+    n = pow2_ceil(ids.size, floor=1)
+    if n == ids.size:
+        return ids
+    return np.concatenate([ids, np.full(n - ids.size, ids[0], ids.dtype)])
+
+
+def _scatter_back(out: jnp.ndarray, idx: np.ndarray, res: jnp.ndarray):
+    """``out[idx] = res[:idx.size]`` as one program per (out, res) shape.
+
+    ``res`` has the bucket's power-of-two row count and ``out`` one spare
+    last row, which takes every pad row of ``res`` (callers slice it off):
+    the scatter does not compile anew for every bucket size, and no index
+    falls outside ``out``.
+    """
+    pos = np.full(res.shape[0], out.shape[0] - 1, np.int32)
+    pos[: idx.size] = idx
+    return out.at[jnp.asarray(pos)].set(res)
 
 
 def _pad_rows(ids: np.ndarray, n: int) -> jnp.ndarray:
@@ -299,9 +327,9 @@ def bucketed_edge_value(
         _host_degrees(layer.memb, un, memb_ov),
         _host_degrees(layer.memb, vn, memb_ov),
     )
-    out = jnp.zeros((B,), jnp.float32)
+    out = jnp.zeros((B + 1,), jnp.float32)  # + the spare row of _scatter_back
     for idx, w in plan_buckets(deg, layer.max_memberships, widths):
-        n = _pow2_rows(idx.size)
+        n = pow2_ceil(idx.size)
         pallas_here = (
             use_pallas
             if use_pallas is not None
@@ -311,8 +339,8 @@ def bucketed_edge_value(
             layer, _pad_rows(un[idx], n), _pad_rows(vn[idx], n),
             width=w, use_pallas=pallas_here, interpret=interpret,
         )
-        out = out.at[jnp.asarray(idx)].set(res[: idx.size])
-    return out.reshape(shape)
+        out = _scatter_back(out, idx, res)
+    return out[:B].reshape(shape)
 
 
 def bucketed_check_edge(layer, u, v, **kw) -> jnp.ndarray:
@@ -355,7 +383,7 @@ def bucketed_node_alters(
     )
     deg = _host_degrees(layer.memb, un, getattr(layer, "memb_ov", None))
     per_node_wn = node_max_hyperedge_size(layer)
-    vals = jnp.full((B, max_alters), SENTINEL, jnp.int32)
+    vals = jnp.full((B + 1, max_alters), SENTINEL, jnp.int32)
     for idx, wm in plan_buckets(deg, layer.max_memberships, widths):
         needed = int(per_node_wn[np.clip(un[idx], 0, per_node_wn.size - 1)].max())
         wn = next(
@@ -363,7 +391,7 @@ def bucketed_node_alters(
             for w in _width_ladder(layer.max_hyperedge_size, widths)
             if w >= needed
         )
-        n = _pow2_rows(idx.size)
+        n = pow2_ceil(idx.size)
         pallas_here = (
             use_pallas
             if use_pallas is not None
@@ -374,8 +402,8 @@ def bucketed_node_alters(
             width_m=wm, width_n=wn, max_alters=max_alters,
             use_pallas=pallas_here, interpret=interpret,
         )
-        vals = vals.at[jnp.asarray(idx)].set(va[: idx.size])
-    vals = vals.reshape(shape + (max_alters,))
+        vals = _scatter_back(vals, idx, va)
+    vals = vals[:B].reshape(shape + (max_alters,))
     return vals, vals != SENTINEL
 
 
@@ -401,17 +429,17 @@ def bucketed_filtered_degree(
     if B == 0:
         return jnp.zeros(shape, jnp.int32)
     nf = jnp.asarray(np.asarray(node_filter, dtype=bool))
-    out = jnp.zeros((B,), jnp.int32)
+    out = jnp.zeros((B + 1,), jnp.int32)
     memb = getattr(layer, "memb", None)
     if memb is None:  # one-mode
         deg = _host_degrees(layer.out, un, layer.out_ov)
         for idx, w in plan_buckets(deg, max(int(deg.max()), 1), widths):
-            n = _pow2_rows(idx.size)
+            n = pow2_ceil(idx.size)
             res = _one_mode_filtered_degree_bucket(
                 layer, _pad_rows(un[idx], n), nf, width=w
             )
-            out = out.at[jnp.asarray(idx)].set(res[: idx.size])
-        return out.reshape(shape)
+            out = _scatter_back(out, idx, res)
+        return out[:B].reshape(shape)
     deg = _host_degrees(memb, un, getattr(layer, "memb_ov", None))
     per_node_wn = node_max_hyperedge_size(layer)
     for idx, wm in plan_buckets(deg, layer.max_memberships, widths):
@@ -421,7 +449,7 @@ def bucketed_filtered_degree(
             for w in _width_ladder(layer.max_hyperedge_size, widths)
             if w >= needed
         )
-        n = _pow2_rows(idx.size)
+        n = pow2_ceil(idx.size)
         pallas_here = (
             use_pallas
             if use_pallas is not None
@@ -433,8 +461,8 @@ def bucketed_filtered_degree(
             use_pallas=pallas_here, interpret=interpret,
         )
         counts = jnp.sum(va != SENTINEL, axis=-1).astype(jnp.int32)
-        out = out.at[jnp.asarray(idx)].set(counts[: idx.size])
-    return out.reshape(shape)
+        out = _scatter_back(out, idx, counts)
+    return out[:B].reshape(shape)
 
 
 def alters_bound(layers, u, n_nodes: int) -> int:

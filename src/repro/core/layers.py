@@ -21,6 +21,8 @@ size-1 batch.
 
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -82,6 +84,17 @@ def _ov_nbytes(ov: DeltaOverlay | None) -> int:
 # ---------------------------------------------------------------------------
 # One-mode layers
 # ---------------------------------------------------------------------------
+
+
+@functools.partial(jax.jit, static_argnames=("max_alters",))
+def _row_alters(csr, ov, u, node_filter, *, max_alters):
+    """Padded neighbor rows, filtered -> (int32[B, max_alters], mask);
+    one compiled program per batch shape."""
+    vals, mask = eff_row_gather(csr, ov, u, max_alters)
+    if node_filter is not None:
+        mask = mask & jnp.take(jnp.asarray(node_filter), vals, mode="clip")
+        vals = jnp.where(mask, vals, SENTINEL)
+    return vals, mask
 
 
 @pytree_dataclass(static=("directed", "valued", "allow_self", "store_inbound"))
@@ -146,13 +159,7 @@ class LayerOneMode:
         attribute predicate (mask holes; ids replaced by SENTINEL).
         """
         csr, ov = self._in_pair() if inbound else (self.out, self.out_ov)
-        vals, mask = eff_row_gather(csr, ov, u, max_alters)
-        if node_filter is not None:
-            mask = mask & jnp.take(
-                jnp.asarray(node_filter), vals, mode="clip"
-            )
-            vals = jnp.where(mask, vals, SENTINEL)
-        return vals, mask
+        return _row_alters(csr, ov, u, node_filter, max_alters=max_alters)
 
     def filtered_degree(self, u: jnp.ndarray, node_filter) -> jnp.ndarray:
         """Count of out-neighbors passing ``node_filter`` -> int32[B].

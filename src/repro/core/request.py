@@ -47,6 +47,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from . import dispatch
 from .nodeset import node_filter_mask
 
 __all__ = [
@@ -472,10 +473,16 @@ def canonical_request(
 # resident single-device replica (``net.source`` when sharded).
 
 
+def _pow2_batch(ids: list) -> jnp.ndarray:
+    """A group's ids padded to a power-of-two length; executors read back
+    only the first ``len(ids)`` answers."""
+    return jnp.asarray(dispatch.pow2_pad(ids), jnp.int32)
+
+
 def _exec_getedge(net, group_key, creqs):
     _, layer_name, _ = group_key
-    u = jnp.asarray([c.ids[0] for c in creqs], jnp.int32)
-    v = jnp.asarray([c.ids2[0] for c in creqs], jnp.int32)
+    u = _pow2_batch([c.ids[0] for c in creqs])
+    v = _pow2_batch([c.ids2[0] for c in creqs])
     nf = creqs[0].mask
     vals = np.asarray(net.edge_value(layer_name, u, v, node_filter=nf))
     return [float(vals[i]) for i in range(len(creqs))]
@@ -483,7 +490,7 @@ def _exec_getedge(net, group_key, creqs):
 
 def _exec_alters(net, group_key, creqs):
     _, layers, max_alters, _ = group_key
-    u = jnp.asarray([c.ids[0] for c in creqs], jnp.int32)
+    u = _pow2_batch([c.ids[0] for c in creqs])
     vals, mask = net.node_alters(
         u, max_alters, layers, node_filter=creqs[0].mask
     )
@@ -495,7 +502,7 @@ def _exec_degree(net, group_key, creqs):
     _, layers, _ = group_key
     flat = [i for c in creqs for i in c.ids]
     out = np.asarray(net.degree(
-        jnp.asarray(flat, jnp.int32), layers, node_filter=creqs[0].mask
+        _pow2_batch(flat), layers, node_filter=creqs[0].mask
     ))
     res, lo = [], 0
     for c in creqs:
@@ -511,7 +518,7 @@ def _exec_khop(net, group_key, creqs):
     _, layers, k, mf, _ = group_key
     flat = [s for c in creqs for s in c.ids]
     nodes, mask, hops = net.khop(
-        jnp.asarray(flat, jnp.int32), k, max_frontier=mf,
+        _pow2_batch(flat), k, max_frontier=mf,
         layer_names=layers, node_filter=creqs[0].mask,
     )
     records = khop_records(flat, nodes, mask, hops)

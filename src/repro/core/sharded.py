@@ -47,10 +47,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from . import dispatch
-from .csr import CSR, SENTINEL
+from .csr import CSR, SENTINEL, sorted_isin
 from .layers import LayerOneMode, LayerTwoMode
 from .network import Network, _as_batch
 from .nodeset import node_filter_mask
@@ -155,12 +154,12 @@ def make_sharded_edge_value(graph: ShardedTwoMode, mesh: Mesh, axis: str = "data
         eq = (a[:, :, None] == b[:, None, :]) & (a != SENTINEL)[:, :, None]
         return jnp.sum(eq, axis=(1, 2)).astype(jnp.float32)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         kernel,
         mesh=mesh,
         in_specs=(P(axis), P(axis), P(), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
 
     @jax.jit
@@ -207,12 +206,12 @@ def make_sharded_walk_step(graph: ShardedTwoMode, mesh: Mesh, axis: str = "data"
         combined = jax.lax.psum(contrib, axis)
         return jnp.where(combined == 0, u, combined - 1).astype(jnp.int32)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         kernel,
         mesh=mesh,
         in_specs=(P(axis), P(axis), P(), P(), P(), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
 
     @jax.jit
@@ -355,6 +354,23 @@ def _slice_layer(layer, lo: int, hi: int):
     )
 
 
+def _owned(ids: np.ndarray, idx: np.ndarray) -> jnp.ndarray:
+    """A shard's sub-batch ``ids[idx]`` padded to a power-of-two length;
+    callers keep the first ``idx.size`` rows of each answer."""
+    return jnp.asarray(dispatch.pow2_pad(ids[idx]), jnp.int32)
+
+
+@jax.jit
+def _member_gather(layer, ids):
+    return layer.memberships(ids)
+
+
+@jax.jit
+def _shared_count(a, am, b, bm):
+    """Shared hyperedges per row of two gathered membership batches."""
+    return jnp.sum(sorted_isin(a, am, b, bm), axis=-1).astype(jnp.float32)
+
+
 class ShardedNetwork:
     """Per-shard row-sliced layer views + the owner-routing query engine.
 
@@ -438,13 +454,10 @@ class ShardedNetwork:
         if isinstance(layer, LayerTwoMode):
             a, am = self._member_rows(layer_name, un)
             b, bm = self._member_rows(layer_name, vn)
-            from .csr import sorted_isin
-
-            hits = sorted_isin(
+            val = _shared_count(
                 jnp.asarray(a), jnp.asarray(am),
                 jnp.asarray(b), jnp.asarray(bm),
             )
-            val = jnp.sum(hits, axis=-1).astype(jnp.float32)
             if nf is not None:
                 val = jnp.where(
                     jnp.take(jnp.asarray(nf), v, mode="clip"), val, 0.0
@@ -455,11 +468,9 @@ class ShardedNetwork:
         def run(part):
             s, idx = part
             vals = self.shards[s].layer(layer_name).edge_value(
-                jnp.asarray(un[idx], jnp.int32),
-                jnp.asarray(vn[idx], jnp.int32),
-                node_filter=nf,
+                _owned(un, idx), _owned(vn, idx), node_filter=nf,
             )
-            return idx, np.asarray(vals)
+            return idx, np.asarray(vals)[: idx.size]
 
         for idx, vals in _smap(run, self._partition(un)):
             out[idx] = vals
@@ -472,8 +483,8 @@ class ShardedNetwork:
         def run(part):
             s, idx = part
             lay = self.shards[s].layer(layer_name)
-            a, m = lay.memberships(jnp.asarray(ids[idx], jnp.int32))
-            return idx, np.asarray(a), np.asarray(m)
+            a, m = _member_gather(lay, _owned(ids, idx))
+            return idx, np.asarray(a)[: idx.size], np.asarray(m)[: idx.size]
 
         parts = _smap(run, self._partition(ids))
         K = max([p[1].shape[1] for p in parts] or [1])
@@ -509,11 +520,9 @@ class ShardedNetwork:
             def run(part, name=name):
                 s, idx = part
                 hit = self.shards[s].layer(name).check_edge(
-                    jnp.asarray(un[idx], jnp.int32),
-                    jnp.asarray(vn[idx], jnp.int32),
-                    node_filter=nf,
+                    _owned(un, idx), _owned(vn, idx), node_filter=nf,
                 )
-                return idx, np.asarray(hit)
+                return idx, np.asarray(hit)[: idx.size]
 
             for idx, hit in _smap(run, self._partition(un)):
                 out[idx] |= hit
@@ -540,10 +549,9 @@ class ShardedNetwork:
         def run(part):
             s, idx = part
             a, m = self.shards[s].node_alters(
-                jnp.asarray(un[idx], jnp.int32), max_alters, layer_names,
-                node_filter=nf,
+                _owned(un, idx), max_alters, layer_names, node_filter=nf,
             )
-            return idx, np.asarray(a), np.asarray(m)
+            return idx, np.asarray(a)[: idx.size], np.asarray(m)[: idx.size]
 
         for idx, a, m in _smap(run, self._partition(un)):
             vals[idx] = a
@@ -562,10 +570,9 @@ class ShardedNetwork:
         def run(part):
             s, idx = part
             d = self.shards[s].degree(
-                jnp.asarray(un[idx], jnp.int32), layer_names,
-                node_filter=nf,
+                _owned(un, idx), layer_names, node_filter=nf,
             )
-            return idx, np.asarray(d)
+            return idx, np.asarray(d)[: idx.size]
 
         for idx, d in _smap(run, self._partition(un)):
             out[idx] = d
@@ -736,7 +743,7 @@ def sharded_khop(
     from repro.kernels import ops as kops
     from .csr import on_tpu as _on_tpu
     from .traversal import (
-        DEFAULT_MAX_FRONTIER, MAX_CAND_FLAT, _frontier_alters,
+        DEFAULT_MAX_FRONTIER, MAX_CAND_FLAT, _frontier_alters, hop_width,
     )
 
     if k < 0:
@@ -789,9 +796,7 @@ def sharded_khop(
             fs_w = int(widths.max())
             if fs_w == 0:
                 continue
-            Fs = 1
-            while Fs < fs_w:  # pow2 width for compile-count stability
-                Fs <<= 1
+            Fs = dispatch.pow2_ceil(fs_w, floor=1)  # compile-count stability
             cols = left[:, None] + np.arange(Fs)[None, :]
             valid = np.arange(Fs)[None, :] < widths[:, None]
             seg = np.where(
@@ -806,9 +811,9 @@ def sharded_khop(
                 cap = max(int(max_alters_per_node), 1)
             else:
                 real = np.unique(seg[seg != int(SENTINEL)].astype(np.int64))
-                cap = dispatch.alters_bound(
+                cap = hop_width(dispatch.alters_bound(
                     shard._select(layer_names), real, n
-                )
+                ))
             Fs = seg.shape[1]
             step = max(1, min(Fs, MAX_CAND_FLAT // cap))
             seg_j = jnp.asarray(seg)

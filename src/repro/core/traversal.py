@@ -33,6 +33,7 @@ callers must pass static caps (``max_alters_per_node``).
 
 from __future__ import annotations
 
+import functools
 from typing import Sequence
 
 import numpy as np
@@ -58,7 +59,7 @@ DEFAULT_MAX_FRONTIER = 4096
 # even when a hub pushes the per-node alter bound toward n_nodes.
 MAX_CAND_FLAT = 65536
 
-_INF = jnp.int32(2**31 - 1)
+_INF = np.int32(2**31 - 1)
 
 
 def _layer_buffers(layer):
@@ -97,7 +98,14 @@ def _hop_cap(
     real = fn[fn != SENTINEL]
     if real.size == 0:
         return 1
-    return dispatch.alters_bound(layers, real, net.n_nodes)
+    return hop_width(dispatch.alters_bound(layers, real, net.n_nodes))
+
+
+def hop_width(bound: int) -> int:
+    """A hop's per-node alter width: the exact bound rounded up to a power
+    of two, so hops compile once per width class (a wider gather only
+    adds SENTINEL padding — results are unchanged)."""
+    return dispatch.pow2_ceil(bound, floor=1)
 
 
 def _frontier_alters(
@@ -124,20 +132,26 @@ def _frontier_alters(
         if un.size == 0:
             return jnp.full((B, F), SENTINEL, jnp.int32)
         alters, _ = net.node_alters(
-            jnp.asarray(un, jnp.int32), cap, layer_names, node_filter=nf
+            jnp.asarray(dispatch.pow2_pad(un), jnp.int32), cap, layer_names,
+            node_filter=nf,
         )
-        pos = np.searchsorted(un, np.where(real, fn, un[0]))
-        cand = jnp.take(alters, jnp.asarray(pos, jnp.int32), axis=0)
-        cand = jnp.where(
-            jnp.asarray(real)[:, None], cand, SENTINEL
-        )
-        return cand.reshape(B, F * cap)
+        pos = np.where(real, np.searchsorted(un, np.where(real, fn, un[0])), -1)
+        return _gather_candidates(alters, jnp.asarray(pos, jnp.int32), B, F)
     real = flat != SENTINEL
     alters, amask = net.node_alters(
         jnp.where(real, flat, 0), cap, layer_names, node_filter=nf
     )
     cand = jnp.where(real[:, None] & amask, alters, SENTINEL)
     return cand.reshape(B, F * cap)
+
+
+@functools.partial(jax.jit, static_argnames=("B", "F"))
+def _gather_candidates(alters, pos, B, F):
+    """Row ``pos[i]`` of ``alters`` per frontier slot (SENTINEL where
+    ``pos`` is -1, a pad slot) -> int32[B, F * cap]."""
+    cand = jnp.take(alters, jnp.maximum(pos, 0), axis=0)
+    cand = jnp.where((pos >= 0)[:, None], cand, SENTINEL)
+    return cand.reshape(B, F * alters.shape[1])
 
 
 def khop_neighborhood(
@@ -201,9 +215,7 @@ def khop_neighborhood(
             used = int(
                 np.sum(np.asarray(frontier) != SENTINEL, axis=1).max()
             )
-            fw = 1
-            while fw < used:
-                fw <<= 1
+            fw = dispatch.pow2_ceil(used, floor=1)
             frontier = frontier[:, : min(fw, frontier.shape[1])]
         cap = _hop_cap(net, frontier, layer_names, max_alters_per_node)
         # slot-chunk the expansion so the (B, slots*cap) candidate buffer

@@ -8,7 +8,7 @@ duplicates (nodes reached from several frontier nodes) and revisits
 first occurrence of every candidate that is NOT in the visited row.
 
 Same machinery as the segmented-union kernel (all-pairs compares beat
-sorting at bucketed widths), plus a third pass over the visited row:
+sorting at bucketed widths), plus a pass over the visited row:
 
   pass 0  seen[i] = any v in visited row with v == cand[i]
   pass 1  kept[i] = valid[i] & ~seen[i] & no j<i with cand[j] == cand[i]
@@ -16,10 +16,11 @@ sorting at bucketed widths), plus a third pass over the visited row:
 
 ``kept``/``rank`` let the caller place each surviving candidate at its
 sorted position with one scatter — sort-free, like segmented_union.
-Grid is (B/block_b,); the candidate row (block_b, Kc) and visited row
-(block_b, Kv) both stay resident and the compare dimension is tiled at
-``block_k``. Padding is SENTINEL in both inputs; SENTINEL candidates are
-never kept, and a SENTINEL visited slot never matches a real candidate.
+Same batch-on-lanes layout: a grid step holds the candidate block
+``(Kc, LANES)`` and the visited block ``(Kv, LANES)``, and every pass
+broadcasts one slot row at a time down the sublanes. Padding is SENTINEL
+in both inputs; SENTINEL candidates are never kept, and a SENTINEL
+visited slot never matches a real candidate.
 """
 
 from __future__ import annotations
@@ -29,92 +30,39 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.csr import SENTINEL
+from .intersect import LANES, vmem_limit
+from .segmented_union import first_occurrence, unique_rank
 
-DEFAULT_BLOCK_B = 8
-DEFAULT_BLOCK_K = 128
 
+def _frontier_kernel(c_ref, v_ref, kept_ref, rank_ref):
+    cand = c_ref[...]  # (Kc, LANES) int32, SENTINEL-padded, unsorted
 
-def _frontier_kernel(c_ref, v_ref, kept_ref, rank_ref, *, block_k: int):
-    bb, Kc = c_ref.shape
-    Kv = v_ref.shape[1]
-    nc = Kc // block_k
-    nv = Kv // block_k
-    cand = c_ref[...]  # (bb, Kc) int32, SENTINEL-padded, unsorted
+    def seen_body(j, seen):
+        return seen | jnp.where(cand == v_ref[pl.ds(j, 1), :], 1, 0)
 
-    # tri[t, s] = s < t (strict lower triangle for the diagonal tile)
-    tri = (
-        jax.lax.broadcasted_iota(jnp.int32, (block_k, block_k), 1)
-        < jax.lax.broadcasted_iota(jnp.int32, (block_k, block_k), 0)
+    seen = jax.lax.fori_loop(
+        0, v_ref.shape[0], seen_body, jnp.zeros(cand.shape, jnp.int32)
     )
-
-    def first_pass(it, _):
-        tile = jax.lax.dynamic_slice(cand, (0, it * block_k), (bb, block_k))
-
-        def dup_inner(jt, dup):
-            cmp = jax.lax.dynamic_slice(
-                cand, (0, jt * block_k), (bb, block_k)
-            )
-            eq = tile[:, :, None] == cmp[:, None, :]  # (bb, bk_t, bk_s)
-            earlier = jnp.where(jt < it, True, jnp.where(jt == it, tri, False))
-            return dup | jnp.any(eq & earlier[None], axis=2)
-
-        def seen_inner(jt, seen):
-            vis = jax.lax.dynamic_slice(
-                v_ref[...], (0, jt * block_k), (bb, block_k)
-            )
-            eq = tile[:, :, None] == vis[:, None, :]
-            return seen | jnp.any(eq, axis=2)
-
-        dup = jax.lax.fori_loop(
-            0, nc, dup_inner, jnp.zeros((bb, block_k), dtype=bool)
-        )
-        seen = jax.lax.fori_loop(
-            0, nv, seen_inner, jnp.zeros((bb, block_k), dtype=bool)
-        )
-        kept = (tile != SENTINEL) & ~dup & ~seen
-        kept_ref[:, pl.ds(it * block_k, block_k)] = kept.astype(jnp.int32)
-        return 0
-
-    jax.lax.fori_loop(0, nc, first_pass, 0)
-
-    def second_pass(it, _):
-        tile = jax.lax.dynamic_slice(cand, (0, it * block_k), (bb, block_k))
-
-        def inner(jt, acc):
-            cmp = jax.lax.dynamic_slice(
-                cand, (0, jt * block_k), (bb, block_k)
-            )
-            kcmp = kept_ref[:, pl.ds(jt * block_k, block_k)]
-            lt = (cmp[:, None, :] < tile[:, :, None]) & (kcmp[:, None, :] > 0)
-            return acc + jnp.sum(lt.astype(jnp.int32), axis=2)
-
-        rank = jax.lax.fori_loop(
-            0, nc, inner, jnp.zeros((bb, block_k), jnp.int32)
-        )
-        rank_ref[:, pl.ds(it * block_k, block_k)] = rank
-        return 0
-
-    jax.lax.fori_loop(0, nc, second_pass, 0)
+    drop = first_occurrence(cand, c_ref, seen)
+    kept_ref[...] = jnp.where((cand != SENTINEL) & (drop == 0), 1, 0)
+    rank_ref[...] = unique_rank(cand, c_ref, kept_ref)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("block_b", "block_k", "interpret")
-)
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def frontier_kernel(
     cand: jnp.ndarray,
     visited: jnp.ndarray,
     *,
-    block_b: int = DEFAULT_BLOCK_B,
-    block_k: int = DEFAULT_BLOCK_K,
     interpret: bool = True,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Per-row first-occurrence-not-visited mask and surviving-value rank.
 
     cand: int32[B, Kc] SENTINEL-padded (unsorted, duplicates allowed);
-    visited: int32[B, Kv] SENTINEL-padded (any order). Kc/Kv must be
-    multiples of block_k and B of block_b (ops.py wrapper pads). Returns
+    visited: int32[B, Kv] SENTINEL-padded (any order). B must be a
+    multiple of LANES and Kc/Kv of 8 (ops.py wrapper pads). Returns
     (kept int32[B, Kc] 0/1, rank int32[B, Kc]); ``rank`` of a kept
     element is its position in the sorted compacted frontier.
     """
@@ -122,25 +70,22 @@ def frontier_kernel(
     Bv, Kv = visited.shape
     if B != Bv:
         raise ValueError(f"batch mismatch {cand.shape} vs {visited.shape}")
-    if B % block_b or Kc % block_k or Kv % block_k:
+    if B % LANES or Kc % 8 or Kv % 8:
         raise ValueError(f"unaligned shapes {cand.shape} / {visited.shape}")
 
-    grid = (B // block_b,)
+    cspec = pl.BlockSpec((Kc, LANES), lambda i: (0, i))
     kept, rank = pl.pallas_call(
-        functools.partial(_frontier_kernel, block_k=block_k),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_b, Kc), lambda i: (i, 0)),
-            pl.BlockSpec((block_b, Kv), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_b, Kc), lambda i: (i, 0)),
-            pl.BlockSpec((block_b, Kc), lambda i: (i, 0)),
-        ],
+        _frontier_kernel,
+        grid=(B // LANES,),
+        in_specs=[cspec, pl.BlockSpec((Kv, LANES), lambda i: (0, i))],
+        out_specs=[cspec, cspec],
         out_shape=[
-            jax.ShapeDtypeStruct((B, Kc), jnp.int32),
-            jax.ShapeDtypeStruct((B, Kc), jnp.int32),
+            jax.ShapeDtypeStruct((Kc, B), jnp.int32),
+            jax.ShapeDtypeStruct((Kc, B), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit((3 * Kc + Kv) * LANES * 4)
+        ),
         interpret=interpret,
-    )(cand, visited)
-    return kept, rank
+    )(cand.T, visited.T)
+    return kept.T, rank.T

@@ -1,25 +1,49 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 Each op pads/aligns inputs to kernel block requirements, dispatches to the
-kernel (interpret=True on CPU — the validation mode; compiled on TPU), and
-slices the result back. ``use_pallas=False`` falls back to the jnp oracle,
+kernel (interpret=True on CPU — the validation mode; always compiled on
+TPU), and slices the result back. ``use_pallas=False`` takes the jnp oracle,
 which is also what the distributed dry-run lowers (kernel bodies are a TPU
 runtime concern, not a sharding concern).
 """
 
 from __future__ import annotations
 
+import functools
+from collections import Counter
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.csr import SENTINEL, on_tpu as _on_tpu, sorted_isin
 from . import ref
 from .frontier import frontier_kernel
-from .intersect import intersect_count_kernel
+from .intersect import LANES, intersect_count_kernel
 from .segmented_union import segmented_union_kernel
 from .flash_attention import flash_attention_kernel
 from .rmsnorm import rmsnorm_kernel
 from .ssd_scan import ssd_scan_kernel
+
+
+#: Entries into each graph kernel's Pallas path: one per eager call and
+#: one per trace under a caller's jit (so a count > 0 means a program that
+#: ran, or will run, holds the kernel). Each eager call is one compiled
+#: program per shape: padding, kernel and scatter-back are jitted together.
+PALLAS_ENTRIES: Counter = Counter()
+
+
+def _interpret(interpret: bool | None) -> bool:
+    """Interpret mode is the CPU validation mode; on a TPU kernels compile.
+
+    ``None`` picks from the backend. An explicit ``True`` on a TPU is an
+    error, never a silent fallback: a kernel that runs there runs compiled.
+    """
+    if interpret is None:
+        return not _on_tpu()
+    if interpret and _on_tpu():
+        raise ValueError("interpret=True on a TPU: kernels compile there")
+    return bool(interpret)
 
 
 def _pad_to(x: jnp.ndarray, axis: int, multiple: int, fill) -> jnp.ndarray:
@@ -47,11 +71,11 @@ def intersect_count(
     """Batched |row∩row| for SENTINEL-padded sorted rows -> int32[B]."""
     if not use_pallas:
         return ref.intersect_count_ref(a, b)
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = _interpret(interpret)
+    PALLAS_ENTRIES["intersect"] += 1
     B = a.shape[0]
-    a = _pad_to(_pad_to(a, 1, 128, SENTINEL), 0, 8, SENTINEL)
-    b = _pad_to(_pad_to(b, 1, 128, SENTINEL), 0, 8, SENTINEL)
+    a = _pad_to(_pad_to(a, 1, 128, SENTINEL), 0, LANES, SENTINEL)
+    b = _pad_to(_pad_to(b, 1, 128, SENTINEL), 0, LANES, SENTINEL)
     out = intersect_count_kernel(a, b, interpret=interpret)
     return out[:B]
 
@@ -94,21 +118,34 @@ def segmented_union(
     smallest unique values — bit-identical outputs.
     """
     if not use_pallas:
-        return ref.segmented_union_ref(flat, max_out)
-    if interpret is None:
-        interpret = not _on_tpu()
-    batch_shape = flat.shape[:-1]
-    f2 = flat.reshape((-1, flat.shape[-1]))
-    B = f2.shape[0]
-    fp = _pad_to(_pad_to(f2, 1, 128, SENTINEL), 0, 8, SENTINEL)
-    kept, rank = segmented_union_kernel(fp, interpret=interpret)
+        return _union_sort(flat, max_out)
+    PALLAS_ENTRIES["segmented_union"] += 1
+    return _union_pallas(flat, max_out, _interpret(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("max_out",))
+def _union_sort(flat, max_out):
+    return ref.segmented_union_ref(flat, max_out)
+
+
+def _place_kept(rows, kept, rank, max_out, batch_shape):
+    """Scatter each kept value of ``rows`` to its rank -> (vals, mask)."""
+    B = int(np.prod(batch_shape, dtype=np.int64))
     keep = (kept > 0) & (rank < max_out)
-    val = jnp.where(keep, fp, SENTINEL)
+    val = jnp.where(keep, rows, SENTINEL)
     pos = jnp.clip(rank, 0, max_out - 1)
-    out = jnp.full((fp.shape[0], max_out), SENTINEL, jnp.int32)
-    out = out.at[jnp.arange(fp.shape[0])[:, None], pos].min(val)
+    out = jnp.full((rows.shape[0], max_out), SENTINEL, jnp.int32)
+    out = out.at[jnp.arange(rows.shape[0])[:, None], pos].min(val)
     out = out[:B].reshape(batch_shape + (max_out,))
     return out, out != SENTINEL
+
+
+@functools.partial(jax.jit, static_argnames=("max_out", "interpret"))
+def _union_pallas(flat, max_out, interpret):
+    f2 = flat.reshape((-1, flat.shape[-1]))
+    fp = _pad_to(_pad_to(f2, 1, 128, SENTINEL), 0, LANES, SENTINEL)
+    kept, rank = segmented_union_kernel(fp, interpret=interpret)
+    return _place_kept(fp, kept, rank, max_out, flat.shape[:-1])
 
 
 def frontier_compact(
@@ -133,36 +170,35 @@ def frontier_compact(
     ascending (SENTINEL pads last) — callers compacting several candidate
     chunks against one visited buffer sort it once, not per chunk.
     """
+    if cand.shape[:-1] != visited.shape[:-1]:
+        raise ValueError(f"batch mismatch {cand.shape} vs {visited.shape}")
     if not use_pallas:
-        # Production jnp path: sort the visited row and exclude by binary
-        # search (O(Kc log Kv)), then the double-sort dedup. The
-        # all-pairs ``frontier_ref`` oracle is O(Kc*Kv) — it exists for
-        # obvious correctness, not speed — outputs are bit-identical.
-        valid = cand != SENTINEL
-        vs = visited if visited_sorted else jnp.sort(visited, axis=-1)
-        seen = sorted_isin(cand, valid, vs, vs != SENTINEL)
-        flat = jnp.where(valid & ~seen, cand, SENTINEL)
-        return ref.segmented_union_ref(flat, max_out)
-    if interpret is None:
-        interpret = not _on_tpu()
-    batch_shape = cand.shape[:-1]
+        return _frontier_sort(cand, visited, max_out, visited_sorted)
+    PALLAS_ENTRIES["frontier"] += 1
+    return _frontier_pallas(cand, visited, max_out, _interpret(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("max_out", "visited_sorted"))
+def _frontier_sort(cand, visited, max_out, visited_sorted):
+    # Production jnp path: sort the visited row and exclude by binary
+    # search (O(Kc log Kv)), then the double-sort dedup. The all-pairs
+    # ``frontier_ref`` oracle is O(Kc*Kv) — it exists for obvious
+    # correctness, not speed — outputs are bit-identical.
+    valid = cand != SENTINEL
+    vs = visited if visited_sorted else jnp.sort(visited, axis=-1)
+    seen = sorted_isin(cand, valid, vs, vs != SENTINEL)
+    flat = jnp.where(valid & ~seen, cand, SENTINEL)
+    return ref.segmented_union_ref(flat, max_out)
+
+
+@functools.partial(jax.jit, static_argnames=("max_out", "interpret"))
+def _frontier_pallas(cand, visited, max_out, interpret):
     c2 = cand.reshape((-1, cand.shape[-1]))
     v2 = visited.reshape((-1, visited.shape[-1]))
-    if c2.shape[0] != v2.shape[0]:
-        raise ValueError(
-            f"batch mismatch {cand.shape} vs {visited.shape}"
-        )
-    B = c2.shape[0]
-    cp = _pad_to(_pad_to(c2, 1, 128, SENTINEL), 0, 8, SENTINEL)
-    vp = _pad_to(_pad_to(v2, 1, 128, SENTINEL), 0, 8, SENTINEL)
+    cp = _pad_to(_pad_to(c2, 1, 128, SENTINEL), 0, LANES, SENTINEL)
+    vp = _pad_to(_pad_to(v2, 1, 128, SENTINEL), 0, LANES, SENTINEL)
     kept, rank = frontier_kernel(cp, vp, interpret=interpret)
-    keep = (kept > 0) & (rank < max_out)
-    val = jnp.where(keep, cp, SENTINEL)
-    pos = jnp.clip(rank, 0, max_out - 1)
-    out = jnp.full((cp.shape[0], max_out), SENTINEL, jnp.int32)
-    out = out.at[jnp.arange(cp.shape[0])[:, None], pos].min(val)
-    out = out[:B].reshape(batch_shape + (max_out,))
-    return out, out != SENTINEL
+    return _place_kept(cp, kept, rank, max_out, cand.shape[:-1])
 
 
 def pseudo_node_alters(
@@ -230,8 +266,7 @@ def flash_attention(
         out = ref.attention_ref(qf, kf, vf, scale=scale, causal=causal,
                                 kv_group=group)
         return out.reshape(B, Hq, S, D)
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = _interpret(interpret)
     bq = min(block_q, S)
     bk = min(block_k, S)
     out = flash_attention_kernel(
@@ -272,8 +307,7 @@ def ssd_scan(
         else:
             out = ref.ssd_scan_ref(xf, dtf, af, bf, cf)
         return out.reshape(B, H, S, P)
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = _interpret(interpret)
     ck = min(chunk, S)
     if S % ck:
         raise ValueError(f"seq {S} not a multiple of chunk {ck}")
@@ -297,8 +331,7 @@ def rmsnorm(
 ) -> jnp.ndarray:
     if not use_pallas:
         return ref.rmsnorm_ref(x, w, eps=eps, plus_one=plus_one)
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = _interpret(interpret)
     shape = x.shape
     x2 = x.reshape(-1, shape[-1])
     R = x2.shape[0]

@@ -9,20 +9,20 @@ is set by the *global* padded width.
 
 TPU adaptation: for bucketed widths (core/dispatch.py) the row is small
 enough that **all-pairs compares** beat sorting, exactly like the
-intersect kernel. Two O(K^2/block) passes over a resident row:
+intersect kernel. Two O(K^2) passes over a resident row:
 
   pass 1  kept[i]  = valid[i] & no j<i with row[j] == row[i]   (first occurrence)
   pass 2  rank[i]  = #{ j : kept[j] & row[j] < row[i] }        (rank among uniques)
 
 ``kept``/``rank`` let the caller place each unique value directly at its
-sorted position with one scatter — no sort at all. Grid is (B/block_b,);
-the full row (block_b, K) stays resident and both passes tile the compare
-dimension at ``block_k`` so intermediates are (block_b, block_k, block_k).
+sorted position with one scatter — no sort at all.
 
-VMEM per step: 3 * block_b * K * 4 B for row/kept/rank plus a
-(block_b, block_k, block_k) compare tile — ~0.7 MiB at block_b=8,
-K=2048, block_k=128, far under budget. Padding is SENTINEL on the input;
-SENTINEL slots are never kept and never compare less-than a real value.
+Layout (as in kernels/intersect.py): the batch sits on the 128 lanes and
+the row slots on the sublanes, so a grid step holds a ``(K, LANES)``
+block and each pass loops over the K slots, broadcasting slot j of every
+row (a ``(1, LANES)`` ref-side slice) down the sublanes in one 2-D
+lane-dense compare. Padding is SENTINEL on the input; SENTINEL slots are
+never kept and never compare less-than a real value.
 """
 
 from __future__ import annotations
@@ -32,97 +32,78 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.csr import SENTINEL
-
-DEFAULT_BLOCK_B = 8
-DEFAULT_BLOCK_K = 128
+from .intersect import LANES, vmem_limit
 
 
-def _union_kernel(v_ref, kept_ref, rank_ref, *, block_k: int):
-    bb, K = v_ref.shape
-    nt = K // block_k
-    row = v_ref[...]  # (bb, K) int32, SENTINEL-padded, unsorted
+def first_occurrence(row, slot_ref, dup):
+    """OR into ``dup`` every slot i whose value occurs at some slot j < i.
 
-    # tri[t, s] = s < t (strict lower triangle for the diagonal tile)
-    tri = (
-        jax.lax.broadcasted_iota(jnp.int32, (block_k, block_k), 1)
-        < jax.lax.broadcasted_iota(jnp.int32, (block_k, block_k), 0)
+    ``row`` is the loaded ``(K, LANES)`` block and ``slot_ref`` its ref;
+    ``dup`` an int32 0/1 array of the same shape.
+    """
+    pos = jax.lax.broadcasted_iota(jnp.int32, row.shape, 0)
+
+    def body(j, dup):
+        vj = slot_ref[pl.ds(j, 1), :]
+        return dup | jnp.where((row == vj) & (pos > j), 1, 0)
+
+    return jax.lax.fori_loop(0, row.shape[0], body, dup)
+
+
+def unique_rank(row, slot_ref, kept_ref):
+    """rank[i] = #{j : kept[j] & row[j] < row[i]} over the K slots."""
+
+    def body(j, rank):
+        vj = slot_ref[pl.ds(j, 1), :]
+        kj = kept_ref[pl.ds(j, 1), :]
+        return rank + jnp.where((vj < row) & (kj > 0), 1, 0)
+
+    return jax.lax.fori_loop(
+        0, row.shape[0], body, jnp.zeros(row.shape, jnp.int32)
     )
 
-    def first_pass(it, _):
-        tile = jax.lax.dynamic_slice(row, (0, it * block_k), (bb, block_k))
 
-        def inner(jt, dup):
-            cmp = jax.lax.dynamic_slice(row, (0, jt * block_k), (bb, block_k))
-            eq = tile[:, :, None] == cmp[:, None, :]  # (bb, bk_t, bk_s)
-            # earlier-index mask: whole tile for jt<it, lower triangle on the
-            # diagonal, nothing for jt>it
-            earlier = jnp.where(jt < it, True, jnp.where(jt == it, tri, False))
-            return dup | jnp.any(eq & earlier[None], axis=2)
-
-        dup = jax.lax.fori_loop(
-            0, nt, inner, jnp.zeros((bb, block_k), dtype=bool)
-        )
-        kept = (tile != SENTINEL) & ~dup
-        kept_ref[:, pl.ds(it * block_k, block_k)] = kept.astype(jnp.int32)
-        return 0
-
-    jax.lax.fori_loop(0, nt, first_pass, 0)
-
-    def second_pass(it, _):
-        tile = jax.lax.dynamic_slice(row, (0, it * block_k), (bb, block_k))
-
-        def inner(jt, acc):
-            cmp = jax.lax.dynamic_slice(row, (0, jt * block_k), (bb, block_k))
-            kcmp = kept_ref[:, pl.ds(jt * block_k, block_k)]
-            lt = (cmp[:, None, :] < tile[:, :, None]) & (kcmp[:, None, :] > 0)
-            return acc + jnp.sum(lt.astype(jnp.int32), axis=2)
-
-        rank = jax.lax.fori_loop(
-            0, nt, inner, jnp.zeros((bb, block_k), jnp.int32)
-        )
-        rank_ref[:, pl.ds(it * block_k, block_k)] = rank
-        return 0
-
-    jax.lax.fori_loop(0, nt, second_pass, 0)
+def _union_kernel(v_ref, kept_ref, rank_ref):
+    row = v_ref[...]  # (K, LANES) int32, SENTINEL-padded, unsorted
+    dup = first_occurrence(row, v_ref, jnp.zeros(row.shape, jnp.int32))
+    kept_ref[...] = jnp.where((row != SENTINEL) & (dup == 0), 1, 0)
+    rank_ref[...] = unique_rank(row, v_ref, kept_ref)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("block_b", "block_k", "interpret")
-)
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def segmented_union_kernel(
     flat: jnp.ndarray,
     *,
-    block_b: int = DEFAULT_BLOCK_B,
-    block_k: int = DEFAULT_BLOCK_K,
     interpret: bool = True,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Per-row first-occurrence mask and unique-value rank.
 
-    flat: int32[B, K] SENTINEL-padded (unsorted); K must be a multiple of
-    block_k and B of block_b (ops.py wrapper pads). Returns
-    (kept int32[B, K] 0/1, rank int32[B, K]); ``rank`` of a kept element is
-    the number of distinct smaller values in the row, i.e. its position in
-    the sorted-unique output.
+    flat: int32[B, K] SENTINEL-padded (unsorted); B must be a multiple of
+    LANES and K of 8 (ops.py wrapper pads). Returns (kept int32[B, K] 0/1,
+    rank int32[B, K]); ``rank`` of a kept element is the number of
+    distinct smaller values in the row, i.e. its position in the
+    sorted-unique output.
     """
     B, K = flat.shape
-    if B % block_b or K % block_k:
+    if B % LANES or K % 8:
         raise ValueError(f"unaligned shape {flat.shape}")
 
-    grid = (B // block_b,)
+    spec = pl.BlockSpec((K, LANES), lambda i: (0, i))
     kept, rank = pl.pallas_call(
-        functools.partial(_union_kernel, block_k=block_k),
-        grid=grid,
-        in_specs=[pl.BlockSpec((block_b, K), lambda i: (i, 0))],
-        out_specs=[
-            pl.BlockSpec((block_b, K), lambda i: (i, 0)),
-            pl.BlockSpec((block_b, K), lambda i: (i, 0)),
-        ],
+        _union_kernel,
+        grid=(B // LANES,),
+        in_specs=[spec],
+        out_specs=[spec, spec],
         out_shape=[
-            jax.ShapeDtypeStruct((B, K), jnp.int32),
-            jax.ShapeDtypeStruct((B, K), jnp.int32),
+            jax.ShapeDtypeStruct((K, B), jnp.int32),
+            jax.ShapeDtypeStruct((K, B), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit(3 * K * LANES * 4)
+        ),
         interpret=interpret,
-    )(flat)
-    return kept, rank
+    )(flat.T)
+    return kept.T, rank.T

@@ -11,30 +11,18 @@ import jax
 from repro.models.sharding import MeshPolicy
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """``axis_types=`` kwargs when this jax has them, else empty.
-
-    ``jax.sharding.AxisType`` (and the matching ``jax.make_mesh`` kwarg)
-    landed after the pinned jax 0.4.37; older versions build every mesh
-    with implicitly-Auto axes, which is exactly what we request on newer
-    versions — so omitting the kwarg is behavior-identical.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
-def make_mesh_compat(shape, axes):
-    """jax.make_mesh with Auto axis types on any supported jax version."""
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+def make_mesh(shape, axes):
+    """jax.make_mesh with every axis Auto (GSPMD-partitioned)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 = 256 chips per pod; 2×16×16 = 512 chips across 2 pods."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 # Models below this size pay more in TP activation collectives than TP
@@ -79,4 +67,4 @@ def make_policy(mesh, model_cfg=None, *, seq_parallel: bool = False) -> MeshPoli
 def make_host_mesh(n_devices: int | None = None, model: int = 1) -> object:
     """Small mesh over the actually-present devices (tests / local runs)."""
     n = n_devices or len(jax.devices())
-    return make_mesh_compat((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))

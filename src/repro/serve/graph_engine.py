@@ -411,6 +411,13 @@ class GraphServeEngine:
         return self._closed
 
     @property
+    def sharded(self):
+        """The ``ShardedNetwork`` executors dispatch against (None when
+        the engine serves one unsharded network)."""
+        with self._lock:
+            return self._sharded
+
+    @property
     def pump_started(self) -> bool:
         return self._thread is not None
 

@@ -62,8 +62,8 @@ def losses(policy, n=4):
             out.append(float(l))
     return out
 
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 pol = MeshPolicy(mesh=mesh, dp=("data",), tp="model")
 sharded = losses(pol)
 single = losses(MeshPolicy())
@@ -88,8 +88,8 @@ from repro.train.checkpoint import save_checkpoint
 
 cfg = get_config("qwen3-1.7b").reduced(n_layers=2, d_model=64, d_ff=128)
 model = Model(cfg)
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 pol = MeshPolicy(mesh=mesh, dp=("data",), tp="model")
 with use_policy(pol):
     params = model.init(jax.random.PRNGKey(7))
@@ -112,8 +112,8 @@ from repro.train.checkpoint import latest_checkpoint, restore_checkpoint
 
 cfg = get_config("qwen3-1.7b").reduced(n_layers=2, d_model=64, d_ff=128)
 model = Model(cfg)
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((2, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2), ("data", "model"))
 pol = MeshPolicy(mesh=mesh, dp=("data",), tp="model")
 with use_policy(pol):
     template = model.init(jax.random.PRNGKey(0))
@@ -146,8 +146,8 @@ from repro.data.pipeline import synthetic_batch_at
 
 cfg = get_config("mamba2-130m").reduced()
 model = Model(cfg)
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 pol = MeshPolicy(mesh=mesh, dp=("data", "model"), tp=None)
 with use_policy(pol):
     params = model.init(jax.random.PRNGKey(0))

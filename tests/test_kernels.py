@@ -191,3 +191,17 @@ def test_rmsnorm_sweep(shape, dtype, plus_one):
         np.asarray(got, np.float32), np.asarray(want, np.float32),
         atol=tol, rtol=tol,
     )
+
+
+@pytest.mark.parametrize("op", ["intersect", "union", "frontier"])
+def test_interpret_refused_on_tpu(monkeypatch, op):
+    """On a TPU a kernel runs compiled; interpret=True there is an error."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    rows = jnp.zeros((8, 128), jnp.int32)
+    with pytest.raises(ValueError, match="interpret=True on a TPU"):
+        if op == "intersect":
+            ops.intersect_count(rows, rows, interpret=True)
+        elif op == "union":
+            ops.segmented_union(rows, 16, interpret=True)
+        else:
+            ops.frontier_compact(rows, rows, 16, interpret=True)

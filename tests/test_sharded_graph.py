@@ -34,8 +34,8 @@ from repro.core import random_two_mode
 from repro.core.sharded import make_sharded_edge_value, shard_two_mode
 
 assert len(jax.devices()) == 8
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((8,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("data",))
 layer = random_two_mode(1000, 40, 4.0, seed=3)
 graph = shard_two_mode(layer, 8)
 edge_value = make_sharded_edge_value(graph, mesh)
@@ -58,8 +58,8 @@ import jax, jax.numpy as jnp
 from repro.core import random_two_mode
 from repro.core.sharded import make_sharded_walk_step, shard_two_mode
 
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((8,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("data",))
 layer = random_two_mode(400, 12, 3.0, seed=5)
 graph = shard_two_mode(layer, 8)
 step = make_sharded_walk_step(graph, mesh)

@@ -54,7 +54,7 @@ import numpy as np  # noqa: E402
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-from repro.core import api, compile_cache, dispatch  # noqa: E402
+from repro.core import api, compile_cache, dispatch, obs  # noqa: E402
 from repro.core.layers import (  # noqa: E402
     LayerTwoMode,
     one_mode_from_edges,
@@ -491,7 +491,8 @@ def main(argv=None) -> int:
         ok = ok and ok1 and ok4 and same
     else:
         _, served_ok = run_phase("serve", net, ref, requests)
-        reached = {k: kops.PALLAS_ENTRIES[k] for k in KERNELS}
+        counters = obs.snapshot()["counters"]
+        reached = {k: counters.get(f"kernels.{k}", 0) for k in KERNELS}
         log(f"[kernels] reached by served requests: {reached}")
         ok = ok and served_ok and all(reached.values())
         ok = kernels_compile(net, platform) and ok

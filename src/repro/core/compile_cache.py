@@ -12,7 +12,8 @@ call :func:`enable` once, before their first compile:
 
 Caching thresholds drop to zero so that the small per-bucket programs,
 which compile in well under JAX's default one second, are cached too.
-:func:`stats` counts the cache requests and hits this process made.
+:func:`stats` counts the cache requests and hits this process made, and
+the compiles (or loads from the cache) of each program by name.
 """
 
 from __future__ import annotations
@@ -26,6 +27,7 @@ import jax
 CHECKOUT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 _counts = {"requests": 0, "hits": 0}
+_programs: dict[str, list] = {}  # fun_name -> [compiles, seconds]
 _lock = threading.Lock()
 _listening = False
 
@@ -40,6 +42,15 @@ def _on_event(event: str, **_kw) -> None:
             _counts[key] += 1
 
 
+def _on_duration(event: str, duration: float, **kw) -> None:
+    if event == "/jax/core/compile/backend_compile_duration":
+        name = str(kw.get("fun_name", "?"))
+        with _lock:
+            entry = _programs.setdefault(name, [0, 0.0])
+            entry[0] += 1
+            entry[1] += float(duration)
+
+
 def enable() -> str:
     """Turn the persistent cache on; returns the directory in use."""
     global _listening
@@ -52,12 +63,16 @@ def enable() -> str:
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     if not _listening:
         jax.monitoring.register_event_listener(_on_event)
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
         _listening = True
     return path
 
 
 def stats() -> dict:
-    """{"requests", "hits", "misses"} of this process's cached compiles."""
+    """{"requests", "hits", "misses"} of this process's cached compiles,
+    and "by_program": {fun_name: [compiles, seconds]}."""
     with _lock:
         req, hits = _counts["requests"], _counts["hits"]
-    return {"requests": req, "hits": hits, "misses": req - hits}
+        by_program = {k: list(v) for k, v in _programs.items()}
+    return {"requests": req, "hits": hits, "misses": req - hits,
+            "by_program": by_program}

@@ -39,6 +39,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from . import obs
 from .csr import CSR, SENTINEL, on_tpu as _on_tpu, sorted_isin
 from .overlay import (
     eff_host_degree_table,
@@ -154,6 +155,15 @@ def _scatter_back(out: jnp.ndarray, idx: np.ndarray, res: jnp.ndarray):
     return out.at[jnp.asarray(pos)].set(res)
 
 
+def _launch(kernel: str | None, width: int, rows: int) -> obs.span:
+    """Count one bucket program (and its Pallas ``kernel``, if it takes
+    that path) and return the span that times its launch."""
+    obs.count("dispatch.buckets")
+    if kernel is not None:
+        obs.count(f"kernels.{kernel}")
+    return obs.span("threadle.dispatch.launch", width=width, rows=rows)
+
+
 def _pad_rows(ids: np.ndarray, n: int) -> jnp.ndarray:
     out = np.zeros((n,), dtype=np.int32)
     out[: ids.size] = ids
@@ -228,6 +238,32 @@ def node_max_hyperedge_size(layer) -> np.ndarray:
         del _NODE_WIDTH_CACHE[next(iter(_NODE_WIDTH_CACHE))]
     _NODE_WIDTH_CACHE[key] = (pins, out)
     return out
+
+
+def _two_hop_plan(layer, un: np.ndarray, widths) -> list[tuple]:
+    """[(positions, membership width, hyperedge width)] per bucket of a
+    two-mode layer: the second hop pads to the widest hyperedge among the
+    bucket's own nodes, rounded up the width ladder (compile-count
+    bound)."""
+    with obs.span("threadle.dispatch.plan"):
+        deg = _host_degrees(layer.memb, un, getattr(layer, "memb_ov", None))
+        per_node_wn = node_max_hyperedge_size(layer)
+        ladder = _width_ladder(layer.max_hyperedge_size, widths)
+        out = []
+        for idx, wm in plan_buckets(deg, layer.max_memberships, widths):
+            needed = int(
+                per_node_wn[np.clip(un[idx], 0, per_node_wn.size - 1)].max()
+            )
+            out.append((idx, wm, next(w for w in ladder if w >= needed)))
+        return out
+
+
+def _union_pallas_here(use_pallas: bool | None, flat_width: int) -> bool:
+    """The segmented-union kernel's auto rule: on a TPU, for rows narrow
+    enough for its all-pairs dedup."""
+    if use_pallas is not None:
+        return use_pallas
+    return _on_tpu() and flat_width <= UNION_PALLAS_MAX_FLAT
 
 
 # ---------------------------------------------------------------------------
@@ -306,13 +342,13 @@ def bucketed_edge_value(
     a fraction of the unfiltered work.
     """
     shape = jnp.shape(u)
-    un = np.asarray(u, dtype=np.int64).reshape(-1)
-    vn = np.asarray(v, dtype=np.int64).reshape(-1)
+    un = obs.fetch(u, np.int64).reshape(-1)
+    vn = obs.fetch(v, np.int64).reshape(-1)
     B = un.size
     if B == 0:
         return jnp.zeros(shape, jnp.float32)
     if node_filter is not None:
-        nf = np.asarray(node_filter, dtype=bool)
+        nf = obs.fetch(node_filter, bool)
         keep = nf[np.clip(vn, 0, nf.size - 1)]
         out = jnp.zeros((B,), jnp.float32)
         if keep.any():
@@ -322,24 +358,27 @@ def bucketed_edge_value(
             )
             out = out.at[jnp.asarray(np.nonzero(keep)[0])].set(sub)
         return out.reshape(shape)
-    memb_ov = getattr(layer, "memb_ov", None)
-    deg = np.maximum(
-        _host_degrees(layer.memb, un, memb_ov),
-        _host_degrees(layer.memb, vn, memb_ov),
-    )
+    with obs.span("threadle.dispatch.plan"):
+        memb_ov = getattr(layer, "memb_ov", None)
+        deg = np.maximum(
+            _host_degrees(layer.memb, un, memb_ov),
+            _host_degrees(layer.memb, vn, memb_ov),
+        )
+        buckets = plan_buckets(deg, layer.max_memberships, widths)
     out = jnp.zeros((B + 1,), jnp.float32)  # + the spare row of _scatter_back
-    for idx, w in plan_buckets(deg, layer.max_memberships, widths):
+    for idx, w in buckets:
         n = pow2_ceil(idx.size)
         pallas_here = (
             use_pallas
             if use_pallas is not None
             else (_on_tpu() and w >= PALLAS_MIN_WIDTH)
         )
-        res = _edge_value_bucket(
-            layer, _pad_rows(un[idx], n), _pad_rows(vn[idx], n),
-            width=w, use_pallas=pallas_here, interpret=interpret,
-        )
-        out = _scatter_back(out, idx, res)
+        with _launch("intersect" if pallas_here else None, w, n):
+            res = _edge_value_bucket(
+                layer, _pad_rows(un[idx], n), _pad_rows(vn[idx], n),
+                width=w, use_pallas=pallas_here, interpret=interpret,
+            )
+            out = _scatter_back(out, idx, res)
     return out[:B].reshape(shape)
 
 
@@ -371,7 +410,7 @@ def bucketed_node_alters(
     alters at full width, drop failing ids, then cap at ``max_alters``).
     """
     shape = jnp.shape(u)
-    un = np.asarray(u, dtype=np.int64).reshape(-1)
+    un = obs.fetch(u, np.int64).reshape(-1)
     B = un.size
     if B == 0:
         return (
@@ -379,30 +418,19 @@ def bucketed_node_alters(
             jnp.zeros(shape + (max_alters,), bool),
         )
     nf = None if node_filter is None else jnp.asarray(
-        np.asarray(node_filter, dtype=bool)
+        obs.fetch(node_filter, bool)
     )
-    deg = _host_degrees(layer.memb, un, getattr(layer, "memb_ov", None))
-    per_node_wn = node_max_hyperedge_size(layer)
     vals = jnp.full((B + 1, max_alters), SENTINEL, jnp.int32)
-    for idx, wm in plan_buckets(deg, layer.max_memberships, widths):
-        needed = int(per_node_wn[np.clip(un[idx], 0, per_node_wn.size - 1)].max())
-        wn = next(
-            w
-            for w in _width_ladder(layer.max_hyperedge_size, widths)
-            if w >= needed
-        )
+    for idx, wm, wn in _two_hop_plan(layer, un, widths):
         n = pow2_ceil(idx.size)
-        pallas_here = (
-            use_pallas
-            if use_pallas is not None
-            else (_on_tpu() and wm * wn <= UNION_PALLAS_MAX_FLAT)
-        )
-        va, _ = _node_alters_bucket(
-            layer, _pad_rows(un[idx], n), nf,
-            width_m=wm, width_n=wn, max_alters=max_alters,
-            use_pallas=pallas_here, interpret=interpret,
-        )
-        vals = _scatter_back(vals, idx, va)
+        pallas_here = _union_pallas_here(use_pallas, wm * wn)
+        with _launch("segmented_union" if pallas_here else None, wm * wn, n):
+            va, _ = _node_alters_bucket(
+                layer, _pad_rows(un[idx], n), nf,
+                width_m=wm, width_n=wn, max_alters=max_alters,
+                use_pallas=pallas_here, interpret=interpret,
+            )
+            vals = _scatter_back(vals, idx, va)
     vals = vals[:B].reshape(shape + (max_alters,))
     return vals, vals != SENTINEL
 
@@ -424,44 +452,35 @@ def bucketed_filtered_degree(
     (wm × wn) so the count is uncapped and exact.
     """
     shape = jnp.shape(u)
-    un = np.asarray(u, dtype=np.int64).reshape(-1)
+    un = obs.fetch(u, np.int64).reshape(-1)
     B = un.size
     if B == 0:
         return jnp.zeros(shape, jnp.int32)
-    nf = jnp.asarray(np.asarray(node_filter, dtype=bool))
+    nf = jnp.asarray(obs.fetch(node_filter, bool))
     out = jnp.zeros((B + 1,), jnp.int32)
-    memb = getattr(layer, "memb", None)
-    if memb is None:  # one-mode
-        deg = _host_degrees(layer.out, un, layer.out_ov)
-        for idx, w in plan_buckets(deg, max(int(deg.max()), 1), widths):
+    if getattr(layer, "memb", None) is None:  # one-mode
+        with obs.span("threadle.dispatch.plan"):
+            deg = _host_degrees(layer.out, un, layer.out_ov)
+            buckets = plan_buckets(deg, max(int(deg.max()), 1), widths)
+        for idx, w in buckets:
             n = pow2_ceil(idx.size)
-            res = _one_mode_filtered_degree_bucket(
-                layer, _pad_rows(un[idx], n), nf, width=w
-            )
-            out = _scatter_back(out, idx, res)
+            with _launch(None, w, n):
+                res = _one_mode_filtered_degree_bucket(
+                    layer, _pad_rows(un[idx], n), nf, width=w
+                )
+                out = _scatter_back(out, idx, res)
         return out[:B].reshape(shape)
-    deg = _host_degrees(memb, un, getattr(layer, "memb_ov", None))
-    per_node_wn = node_max_hyperedge_size(layer)
-    for idx, wm in plan_buckets(deg, layer.max_memberships, widths):
-        needed = int(per_node_wn[np.clip(un[idx], 0, per_node_wn.size - 1)].max())
-        wn = next(
-            w
-            for w in _width_ladder(layer.max_hyperedge_size, widths)
-            if w >= needed
-        )
+    for idx, wm, wn in _two_hop_plan(layer, un, widths):
         n = pow2_ceil(idx.size)
-        pallas_here = (
-            use_pallas
-            if use_pallas is not None
-            else (_on_tpu() and wm * wn <= UNION_PALLAS_MAX_FLAT)
-        )
-        va, _ = _node_alters_bucket(
-            layer, _pad_rows(un[idx], n), nf,
-            width_m=wm, width_n=wn, max_alters=wm * wn,
-            use_pallas=pallas_here, interpret=interpret,
-        )
-        counts = jnp.sum(va != SENTINEL, axis=-1).astype(jnp.int32)
-        out = _scatter_back(out, idx, counts)
+        pallas_here = _union_pallas_here(use_pallas, wm * wn)
+        with _launch("segmented_union" if pallas_here else None, wm * wn, n):
+            va, _ = _node_alters_bucket(
+                layer, _pad_rows(un[idx], n), nf,
+                width_m=wm, width_n=wn, max_alters=wm * wn,
+                use_pallas=pallas_here, interpret=interpret,
+            )
+            counts = jnp.sum(va != SENTINEL, axis=-1).astype(jnp.int32)
+            out = _scatter_back(out, idx, counts)
     return out[:B].reshape(shape)
 
 
@@ -516,8 +535,9 @@ def union_rows(
     from repro.kernels import ops as kops
 
     flat = jnp.where(valid, vals, SENTINEL)
-    if use_pallas is None:
-        use_pallas = _on_tpu() and flat.shape[-1] <= UNION_PALLAS_MAX_FLAT
+    use_pallas = _union_pallas_here(use_pallas, flat.shape[-1])
+    if use_pallas and can_dispatch(flat):
+        obs.count("kernels.segmented_union")
     return kops.segmented_union(
         flat, max_out, use_pallas=use_pallas, interpret=interpret
     )

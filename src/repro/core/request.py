@@ -47,7 +47,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from . import dispatch
+from . import dispatch, obs
 from .nodeset import node_filter_mask
 
 __all__ = [
@@ -484,7 +484,7 @@ def _exec_getedge(net, group_key, creqs):
     u = _pow2_batch([c.ids[0] for c in creqs])
     v = _pow2_batch([c.ids2[0] for c in creqs])
     nf = creqs[0].mask
-    vals = np.asarray(net.edge_value(layer_name, u, v, node_filter=nf))
+    vals = obs.fetch(net.edge_value(layer_name, u, v, node_filter=nf))
     return [float(vals[i]) for i in range(len(creqs))]
 
 
@@ -494,14 +494,14 @@ def _exec_alters(net, group_key, creqs):
     vals, mask = net.node_alters(
         u, max_alters, layers, node_filter=creqs[0].mask
     )
-    vals, mask = np.asarray(vals), np.asarray(mask)
+    vals, mask = obs.fetch(vals), obs.fetch(mask)
     return [vals[i][mask[i]] for i in range(len(creqs))]
 
 
 def _exec_degree(net, group_key, creqs):
     _, layers, _ = group_key
     flat = [i for c in creqs for i in c.ids]
-    out = np.asarray(net.degree(
+    out = obs.fetch(net.degree(
         _pow2_batch(flat), layers, node_filter=creqs[0].mask
     ))
     res, lo = [], 0
@@ -521,7 +521,9 @@ def _exec_khop(net, group_key, creqs):
         _pow2_batch(flat), k, max_frontier=mf,
         layer_names=layers, node_filter=creqs[0].mask,
     )
-    records = khop_records(flat, nodes, mask, hops)
+    records = khop_records(
+        flat, obs.fetch(nodes), obs.fetch(mask), obs.fetch(hops)
+    )
     res, lo = [], 0
     for c in creqs:
         hi = lo + len(c.ids)
@@ -561,7 +563,7 @@ def _exec_walkbatch(net, group_key, creqs):
         creqs[0].mask, steps=steps, walkers=walkers, layer_names=layers,
         layer_weights=weights,
     )
-    return [np.asarray(paths, dtype=np.int32)] * len(creqs)
+    return [obs.fetch(paths, np.int32)] * len(creqs)
 
 
 _EXECUTORS = {

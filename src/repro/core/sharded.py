@@ -48,7 +48,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from . import dispatch
+from . import dispatch, obs
 from .csr import CSR, SENTINEL, sorted_isin
 from .layers import LayerOneMode, LayerTwoMode
 from .network import Network, _as_batch
@@ -830,6 +830,8 @@ def sharded_khop(
                         and cand.shape[-1] <= dispatch.UNION_PALLAS_MAX_FLAT
                     )
                 )
+                if pallas_here:
+                    obs.count("kernels.frontier")
                 pv, pm = kops.frontier_compact(
                     cand, visited_hop, max_frontier,
                     use_pallas=pallas_here, interpret=interpret,

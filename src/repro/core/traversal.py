@@ -40,7 +40,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from . import dispatch
+from . import dispatch, obs
 from .csr import SENTINEL, on_tpu as _on_tpu
 from .nodeset import node_filter_mask
 
@@ -212,9 +212,7 @@ def khop_neighborhood(
         # expensive expansion — typical frontiers fill a fraction of
         # max_frontier
         if dispatch.can_dispatch(frontier) and frontier.shape[1] > 1:
-            used = int(
-                np.sum(np.asarray(frontier) != SENTINEL, axis=1).max()
-            )
+            used = int(np.sum(obs.fetch(frontier) != SENTINEL, axis=1).max())
             fw = dispatch.pow2_ceil(used, floor=1)
             frontier = frontier[:, : min(fw, frontier.shape[1])]
         cap = _hop_cap(net, frontier, layer_names, max_alters_per_node)
@@ -243,6 +241,8 @@ def khop_neighborhood(
                     and cand.shape[-1] <= dispatch.UNION_PALLAS_MAX_FLAT
                 )
             )
+            if pallas_here and dispatch.can_dispatch(cand):
+                obs.count("kernels.frontier")
             pv, pm = kops.frontier_compact(
                 cand, visited_hop, max_frontier,
                 use_pallas=pallas_here, interpret=interpret,

@@ -87,5 +87,6 @@ def frontier_kernel(
             vmem_limit_bytes=vmem_limit((3 * Kc + Kv) * LANES * 4)
         ),
         interpret=interpret,
+        name="frontier",
     )(cand.T, visited.T)
     return kept.T, rank.T

@@ -109,5 +109,6 @@ def intersect_count_kernel(
             vmem_limit_bytes=vmem_limit(Ka * LANES * 4)
         ),
         interpret=interpret,
+        name="intersect",
     )(a.T, b.T)
     return out[0]

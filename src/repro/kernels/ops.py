@@ -10,7 +10,6 @@ runtime concern, not a sharding concern).
 from __future__ import annotations
 
 import functools
-from collections import Counter
 
 import jax
 import jax.numpy as jnp
@@ -24,13 +23,6 @@ from .segmented_union import segmented_union_kernel
 from .flash_attention import flash_attention_kernel
 from .rmsnorm import rmsnorm_kernel
 from .ssd_scan import ssd_scan_kernel
-
-
-#: Entries into each graph kernel's Pallas path: one per eager call and
-#: one per trace under a caller's jit (so a count > 0 means a program that
-#: ran, or will run, holds the kernel). Each eager call is one compiled
-#: program per shape: padding, kernel and scatter-back are jitted together.
-PALLAS_ENTRIES: Counter = Counter()
 
 
 def _interpret(interpret: bool | None) -> bool:
@@ -72,7 +64,6 @@ def intersect_count(
     if not use_pallas:
         return ref.intersect_count_ref(a, b)
     interpret = _interpret(interpret)
-    PALLAS_ENTRIES["intersect"] += 1
     B = a.shape[0]
     a = _pad_to(_pad_to(a, 1, 128, SENTINEL), 0, LANES, SENTINEL)
     b = _pad_to(_pad_to(b, 1, 128, SENTINEL), 0, LANES, SENTINEL)
@@ -119,7 +110,6 @@ def segmented_union(
     """
     if not use_pallas:
         return _union_sort(flat, max_out)
-    PALLAS_ENTRIES["segmented_union"] += 1
     return _union_pallas(flat, max_out, _interpret(interpret))
 
 
@@ -174,7 +164,6 @@ def frontier_compact(
         raise ValueError(f"batch mismatch {cand.shape} vs {visited.shape}")
     if not use_pallas:
         return _frontier_sort(cand, visited, max_out, visited_sorted)
-    PALLAS_ENTRIES["frontier"] += 1
     return _frontier_pallas(cand, visited, max_out, _interpret(interpret))
 
 

@@ -105,5 +105,6 @@ def segmented_union_kernel(
             vmem_limit_bytes=vmem_limit(3 * K * LANES * 4)
         ),
         interpret=interpret,
+        name="segmented_union",
     )(flat.T)
     return kept.T, rank.T

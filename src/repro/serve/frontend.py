@@ -63,6 +63,7 @@ import time
 
 import re
 
+from repro.core import obs
 from repro.core.request import QueryRequest
 
 from .faults import ConnectionDropped
@@ -200,22 +201,24 @@ class _Handler(socketserver.StreamRequestHandler):
 
     def _handle_line(self, fe: "GraphServeFrontend", sid: int,
                      line: bytes) -> None:
-        text = line.decode("utf-8", errors="replace").strip()
-        if not text:
-            return
-        try:
-            env = json.loads(text)
-            if not isinstance(env, dict):
-                raise ValueError("envelope must be a JSON object")
-        except ValueError as e:
-            # echo the request id when one is recognizable in the broken
-            # line, so clients can correlate the error to their retry
-            self._reply(fe, _err(_salvage_id(text), "bad_request",
-                                 f"bad envelope: {e}"))
-            return
-        resp = fe._dispatch(sid, env)
-        if resp is not None:
-            self._reply(fe, resp)
+        with obs.span("threadle.frontend.request"):
+            text = line.decode("utf-8", errors="replace").strip()
+            if not text:
+                return
+            try:
+                env = json.loads(text)
+                if not isinstance(env, dict):
+                    raise ValueError("envelope must be a JSON object")
+            except ValueError as e:
+                # echo the request id when one is recognizable in the
+                # broken line, so clients can correlate the error to
+                # their retry
+                self._reply(fe, _err(_salvage_id(text), "bad_request",
+                                     f"bad envelope: {e}"))
+                return
+            resp = fe._dispatch(sid, env)
+            if resp is not None:
+                self._reply(fe, resp)
 
     def _reply(self, fe: "GraphServeFrontend", resp: dict) -> None:
         plan = fe._plan
@@ -466,7 +469,9 @@ class GraphServeFrontend:
         wait = self._result_timeout
         if deadline is not None:
             wait = max(min(wait, deadline - time.monotonic()), 1e-4)
-        res = self.engine.result(qid, timeout=wait)
+        obs.tag(rid=qid)  # on threadle.frontend.request
+        with obs.span("threadle.frontend.wait", rid=qid):
+            res = self.engine.result(qid, timeout=wait)
         if res is None:
             return _err(rid, "deadline",
                         "DeadlineExceeded: no result within budget")

@@ -110,6 +110,7 @@ from repro.core.request import (  # noqa: F401  (re-exported serving API)
     canonical_request,
     run_request,
 )
+from repro.core import obs
 from repro.core.sharded import reshard_deltas, shard_network
 
 
@@ -207,6 +208,7 @@ class _Pending:
     raw: dict  # original request — re-canonicalized if the net mutates
     gen: int = 0  # engine generation the canonicalization ran against
     deadline: float | None = None  # time.monotonic() expiry, None = never
+    enqueued_ns: int = 0  # time.perf_counter_ns() at enqueue
 
 
 class GraphServeEngine:
@@ -292,6 +294,7 @@ class GraphServeEngine:
         self._coalesced_dupes = 0
         self._deadline_expired = 0
         self._pump_faults = 0
+        self._rounds = 0
         self._filter_memo: dict = {}
         # chaos-harness hook (serve/faults.py): sites "engine.exec"
         # (injected executor exception) and "pump.batch_delay" (delay
@@ -328,49 +331,55 @@ class GraphServeEngine:
         ``QueryRequest`` — the single currency shared with ``api`` and
         the wire frontend.
         """
-        if isinstance(request, QueryRequest):
-            request = request.to_dict()
-        timeout = request.get("timeout", self.default_timeout)
-        deadline = None
-        if timeout is not None:
-            timeout = float(timeout)
-            if timeout <= 0:
-                raise ValueError(f"timeout must be > 0, got {timeout}")
-            deadline = time.monotonic() + timeout
-        with self._lock:
-            if self._closed:
-                raise EngineClosed("engine is closed; no new submissions")
-            gen, net = self._generation, self.net
-        # canonicalization (filter resolution can touch the attribute
-        # store) runs outside the lock; if a mutation lands in between,
-        # the enqueued snapshot ``gen`` no longer matches and pump()
-        # re-canonicalizes against the current network at pop time —
-        # the same path every queued-then-mutated request takes
-        creq = canonical_request(
-            net, request, _filter_memo=self._filter_memo, _gen=gen
-        )
-        q, limit = (
-            (self._point, self._queue_limit)
-            if creq.kind in POINT_KINDS
-            else (self._heavy, self._heavy_limit)
-        )
-        with self._lock:
-            if self._closed:  # closed while we canonicalized
-                raise EngineClosed("engine is closed; no new submissions")
-            if len(q) >= limit:
-                if _count_rejection:
-                    self._rejected += 1
-                raise QueueFull(
-                    f"{creq.kind!r} queue at limit ({limit}); drain "
-                    "with pump() or raise queue_limit"
-                )
-            rid = self._next_rid
-            self._next_rid += 1
-            if _claim:
-                self._claimed.add(rid)
-            q.append(_Pending(rid, creq, dict(request), gen, deadline))
-            self._work.notify()
-        return rid
+        with obs.span("threadle.engine.submit"):
+            if isinstance(request, QueryRequest):
+                request = request.to_dict()
+            timeout = request.get("timeout", self.default_timeout)
+            deadline = None
+            if timeout is not None:
+                timeout = float(timeout)
+                if timeout <= 0:
+                    raise ValueError(f"timeout must be > 0, got {timeout}")
+                deadline = time.monotonic() + timeout
+            with self._lock:
+                if self._closed:
+                    raise EngineClosed(
+                        "engine is closed; no new submissions"
+                    )
+                gen, net = self._generation, self.net
+            # canonicalization (filter resolution can touch the attribute
+            # store) runs outside the lock; if a mutation lands in between,
+            # the enqueued snapshot ``gen`` no longer matches and pump()
+            # re-canonicalizes against the current network at pop time —
+            # the same path every queued-then-mutated request takes
+            creq = canonical_request(
+                net, request, _filter_memo=self._filter_memo, _gen=gen
+            )
+            q, limit = (
+                (self._point, self._queue_limit)
+                if creq.kind in POINT_KINDS
+                else (self._heavy, self._heavy_limit)
+            )
+            with self._lock:
+                if self._closed:  # closed while we canonicalized
+                    raise EngineClosed(
+                        "engine is closed; no new submissions"
+                    )
+                if len(q) >= limit:
+                    if _count_rejection:
+                        self._rejected += 1
+                    raise QueueFull(
+                        f"{creq.kind!r} queue at limit ({limit}); drain "
+                        "with pump() or raise queue_limit"
+                    )
+                rid = self._next_rid
+                self._next_rid += 1
+                if _claim:
+                    self._claimed.add(rid)
+                q.append(_Pending(rid, creq, dict(request), gen, deadline,
+                                  time.perf_counter_ns()))
+                self._work.notify()
+            return rid
 
     def result(
         self, rid: int, *, timeout: float | None = None
@@ -442,54 +451,68 @@ class GraphServeEngine:
         """
         with self._lock:
             popped = list(self._point)
+            n_point = len(popped)
             self._point.clear()
             for _ in range(min(self._max_heavy, len(self._heavy))):
                 popped.append(self._heavy.popleft())
             net, generation = self.net, self._generation
             target = self._sharded if self._sharded is not None else net
+            if popped:
+                self._rounds += 1
+                round_no = self._rounds
         if not popped:
             return 0
+        now = time.perf_counter_ns()
+        for cls, ps in (("point", popped[:n_point]),
+                        ("heavy", popped[n_point:])):
+            if ps:
+                obs.count(f"engine.popped.{cls}", len(ps))
+                obs.count(f"engine.queue_wait_ns.{cls}",
+                          sum(now - p.enqueued_ns for p in ps))
 
-        finished: list[QueryResult] = []
-        try:
-            self._pump_round(popped, net, generation, finished, target)
-        except Exception as e:
-            answered = {r.rid for r in finished}
-            msg = f"pump fault: {type(e).__name__}: {e}"
-            for p in popped:
-                if p.rid not in answered:
-                    finished.append(
-                        QueryResult(p.rid, p.creq.kind, None, error=msg)
-                    )
+        with obs.span("threadle.engine.round", round=round_no,
+                      n=len(popped)):
+            finished: list[QueryResult] = []
+            try:
+                self._pump_round(popped, net, generation, finished, target,
+                                 round_no)
+            except Exception as e:
+                answered = {r.rid for r in finished}
+                msg = f"pump fault: {type(e).__name__}: {e}"
+                for p in popped:
+                    if p.rid not in answered:
+                        finished.append(
+                            QueryResult(p.rid, p.creq.kind, None, error=msg)
+                        )
+                with self._lock:
+                    self._pump_faults += 1
+
             with self._lock:
-                self._pump_faults += 1
-
-        with self._lock:
-            for r in finished:
-                self._results[r.rid] = r
-            # bound the store against fire-and-forget clients: drop the
-            # oldest-stored results first (insertion-ordered dict),
-            # skipping rids an in-progress serve() replay has claimed —
-            # one scan per round, not one per drop (claimed entries sit
-            # at the front and would make repeated next() quadratic)
-            excess = len(self._results) - self._result_limit
-            if excess > 0:
-                victims = []
-                for k in self._results:
-                    if k not in self._claimed:
-                        victims.append(k)
-                        if len(victims) == excess:
-                            break
-                for k in victims:
-                    self._results.pop(k)
-                self._results_dropped += len(victims)
-            self._served += len(finished)
-            self._done.notify_all()
+                for r in finished:
+                    self._results[r.rid] = r
+                # bound the store against fire-and-forget clients: drop the
+                # oldest-stored results first (insertion-ordered dict),
+                # skipping rids an in-progress serve() replay has claimed —
+                # one scan per round, not one per drop (claimed entries sit
+                # at the front and would make repeated next() quadratic)
+                excess = len(self._results) - self._result_limit
+                if excess > 0:
+                    victims = []
+                    for k in self._results:
+                        if k not in self._claimed:
+                            victims.append(k)
+                            if len(victims) == excess:
+                                break
+                    for k in victims:
+                        self._results.pop(k)
+                    self._results_dropped += len(victims)
+                self._served += len(finished)
+                self._done.notify_all()
         return len(finished)
 
     def _pump_round(
         self, popped: list[_Pending], net, generation: int,
-        finished: list[QueryResult], target=None,
+        finished: list[QueryResult], target=None, round_no: int = 0,
     ) -> None:
         """The fallible middle of a pump round; appends to ``finished``.
 
@@ -566,7 +589,9 @@ class GraphServeEngine:
             try:
                 if self._fault_plan:
                     self._fault_plan.fire("engine.exec")
-                values = _EXECUTORS[kind](target, group_key, creqs)
+                with obs.span("threadle.engine.group", kind=kind,
+                              n=len(creqs), round=round_no):
+                    values = _EXECUTORS[kind](target, group_key, creqs)
                 if self._fault_plan:  # chaos: stall between exec + scatter
                     self._fault_plan.fire("pump.batch_delay")
                 errs = [None] * len(values)
@@ -969,6 +994,8 @@ class GraphServeEngine:
                 "durable_lsn": (
                     None if self._store is None else self._store.last_lsn
                 ),
+                # the process's span and counter table (core/obs.py)
+                "trace": obs.snapshot(),
             }
 
 

@@ -2,7 +2,7 @@
 
 * The persistent compilation cache (core/compile_cache.py) lives where
   ``JAX_COMPILATION_CACHE_DIR`` says, else at ``<checkout>/.jax_cache``,
-  and a second identical process hits it.
+  and a second identical process hits it; its stats name each program.
 * Importing the engine creates no JAX backend: a parent that imports
   ``repro`` and then starts a JAX child must not hold the device.
 """
@@ -40,6 +40,9 @@ def test_cache_dir_from_env_is_used_and_hit(tmp_path):
     first = json.loads(_child(_CACHED_RUN, JAX_COMPILATION_CACHE_DIR=str(cache)))
     assert first["path"] == str(cache)
     assert first["requests"] >= 1 and first["hits"] == 0
+    # each compiled program is named, with its count and seconds
+    assert first["by_program"]["jit(<lambda>)"][0] == 1
+    assert sum(c for c, _ in first["by_program"].values()) == first["requests"]
     assert any(cache.iterdir())
     second = json.loads(
         _child(_CACHED_RUN, JAX_COMPILATION_CACHE_DIR=str(cache))

@@ -19,7 +19,15 @@ global-max padded path), the dispatcher
   4. runs each bucket through a jit'd fixed-width kernel — the Pallas
      intersect / segmented-union kernels for wide buckets on TPU, the jnp
      ``sorted_isin`` / ``padded_unique`` paths for tiny buckets and CPU —
-  5. scatters per-bucket results back into the original batch order.
+  5. launches every bucket back to back, brings all their results to
+     the host in one fetch, and scatters them into the batch's order
+     there.
+
+The cores (``edge_value_host``, ``node_alters_host``,
+``filtered_degree_host``) take host ids and return host arrays: the
+serving path's form, where ids arrive from the wire and answers leave
+on it. The ``bucketed_*`` wrappers take ids from anywhere and return a
+jax array, converted once.
 
 For ``node_alters`` the second-hop width is also bucket-local: the max
 hyperedge size *among the bucket's actual hyperedges* (cached per layer),
@@ -51,7 +59,12 @@ from .overlay import (
 __all__ = [
     "DEFAULT_BUCKET_WIDTHS",
     "can_dispatch",
+    "host_ids",
     "plan_buckets",
+    "edge_value_host",
+    "node_alters_host",
+    "filtered_degree_host",
+    "degree_sum",
     "bucketed_edge_value",
     "bucketed_check_edge",
     "bucketed_node_alters",
@@ -72,6 +85,11 @@ PALLAS_MIN_WIDTH = 128
 UNION_PALLAS_MAX_FLAT = 2048
 
 
+# in the table from the start, so that ``/stats`` reads 0 until a call
+for _name in ("dispatch.host_ids", "dispatch.device_ids"):
+    obs.count(_name, 0)
+
+
 def can_dispatch(*arrays) -> bool:
     """True when every array is concrete (not inside a jit trace).
 
@@ -80,6 +98,18 @@ def can_dispatch(*arrays) -> bool:
     traced even when the queries are host arrays.
     """
     return not any(isinstance(a, jax.core.Tracer) for a in arrays)
+
+
+def host_ids(*ids) -> list[np.ndarray]:
+    """Each id array as a flat int64 host array.
+
+    Counts the call as ``dispatch.host_ids``, or as
+    ``dispatch.device_ids`` where any array had to come back from the
+    device (in one fetch, with the others).
+    """
+    on_device = any(isinstance(a, jax.Array) for a in ids)
+    obs.count("dispatch.device_ids" if on_device else "dispatch.host_ids")
+    return [a.reshape(-1) for a in obs.fetch(list(ids), np.int64)]
 
 
 # ---------------------------------------------------------------------------
@@ -142,19 +172,6 @@ def pow2_pad(ids) -> np.ndarray:
     return np.concatenate([ids, np.full(n - ids.size, ids[0], ids.dtype)])
 
 
-def _scatter_back(out: jnp.ndarray, idx: np.ndarray, res: jnp.ndarray):
-    """``out[idx] = res[:idx.size]`` as one program per (out, res) shape.
-
-    ``res`` has the bucket's power-of-two row count and ``out`` one spare
-    last row, which takes every pad row of ``res`` (callers slice it off):
-    the scatter does not compile anew for every bucket size, and no index
-    falls outside ``out``.
-    """
-    pos = np.full(res.shape[0], out.shape[0] - 1, np.int32)
-    pos[: idx.size] = idx
-    return out.at[jnp.asarray(pos)].set(res)
-
-
 def _launch(kernel: str | None, width: int, rows: int) -> obs.span:
     """Count one bucket program (and its Pallas ``kernel``, if it takes
     that path) and return the span that times its launch."""
@@ -164,10 +181,19 @@ def _launch(kernel: str | None, width: int, rows: int) -> obs.span:
     return obs.span("threadle.dispatch.launch", width=width, rows=rows)
 
 
-def _pad_rows(ids: np.ndarray, n: int) -> jnp.ndarray:
+def _pad_rows(ids: np.ndarray, n: int) -> np.ndarray:
+    """A bucket's ids padded to ``n`` rows, on the host: the jitted
+    bucket program takes them as its argument."""
     out = np.zeros((n,), dtype=np.int32)
     out[: ids.size] = ids
-    return jnp.asarray(out)
+    return out
+
+
+def _fetch_buckets(launched: list) -> list[tuple[np.ndarray, np.ndarray]]:
+    """[(positions, device result)] -> [(positions, its real rows)]: every
+    bucket's result comes to the host in one fetch."""
+    res = obs.fetch([r for _, r in launched])
+    return [(idx, r[: idx.size]) for (idx, _), r in zip(launched, res)]
 
 
 # Per-layer cache: node -> max hyperedge size over its memberships.
@@ -315,22 +341,90 @@ def _one_mode_filtered_degree_bucket(layer, u, node_filter, *, width):
     return jnp.sum(hit, axis=-1).astype(jnp.int32)
 
 
+@functools.partial(
+    jax.jit, static_argnames=("width_m", "width_n", "use_pallas", "interpret")
+)
+def _two_mode_filtered_degree_bucket(
+    layer, u, node_filter, *, width_m, width_n, use_pallas, interpret,
+):
+    """Distinct co-members passing the filter: the filtered alters at the
+    bucket's exact flat width (uncapped), counted on the device."""
+    from repro.kernels import ops as kops
+
+    vals, _ = kops.pseudo_node_alters(
+        layer, u, width_m * width_n,
+        width_m=width_m, width_n=width_n, node_filter=node_filter,
+        use_pallas=use_pallas, interpret=interpret,
+    )
+    return jnp.sum(vals != SENTINEL, axis=-1).astype(jnp.int32)
+
+
+def _degree_csr(layer):
+    """(CSR, overlay) whose row lengths are ``layer.degrees()``."""
+    if getattr(layer, "memb", None) is not None:
+        return layer.memb, layer.memb_ov
+    return layer.out, layer.out_ov
+
+
+def _row_lengths(indptr, r):
+    return jnp.take(indptr, r + 1, mode="clip") - jnp.take(
+        indptr, r, mode="clip"
+    )
+
+
+@jax.jit
+def _degree_sum(parts, u):
+    total = jnp.zeros(jnp.shape(u), jnp.int32)
+    for indptr, ov in parts:
+        # eff_degrees: rows past the base read 0, dirty rows the delta's
+        n_base = indptr.shape[0] - 1
+        n = n_base if ov is None else ov[0].shape[0] - 1
+        r = jnp.clip(u, 0, max(n - 1, 0))
+        deg = jnp.where(r < n_base, _row_lengths(indptr, r), 0)
+        if ov is not None:
+            dptr, dirty = ov
+            deg = jnp.where(
+                jnp.take(dirty, r, mode="clip"), _row_lengths(dptr, r), deg
+            )
+        total = total + deg.astype(jnp.int32)
+    return total
+
+
+def degree_sum(layers, u) -> jnp.ndarray:
+    """Summed per-layer degree (two-mode: membership count) -> int32[...].
+
+    One program per (layer set, batch shape): each layer's ``indptr`` is
+    read at ``u`` and ``u + 1`` only, overlay-aware, with the clip of
+    ``jnp.take(layer.degrees(), u, mode="clip")``, so no layer's whole
+    degree vector is computed. ``u`` may be host ids, a device array or
+    a tracer.
+    """
+    parts = []
+    for layer in layers:
+        csr, ov = _degree_csr(layer)
+        parts.append(
+            (csr.indptr, None if ov is None else (ov.delta.indptr, ov.dirty))
+        )
+    return _degree_sum(tuple(parts), u)
+
+
 # ---------------------------------------------------------------------------
-# Dispatchers
+# Dispatchers: host cores
 # ---------------------------------------------------------------------------
 
 
-def bucketed_edge_value(
+def edge_value_host(
     layer,
-    u: jnp.ndarray,
-    v: jnp.ndarray,
+    un: np.ndarray,
+    vn: np.ndarray,
     *,
     node_filter=None,
     widths=DEFAULT_BUCKET_WIDTHS,
     use_pallas: bool | None = None,
     interpret: bool | None = None,
-) -> jnp.ndarray:
-    """Degree-bucketed GetEdgeValue over a concrete query batch -> f32[...].
+) -> np.ndarray:
+    """Degree-bucketed GetEdgeValue over flat host ids -> float32[B] on the
+    host.
 
     Buckets by max(deg(u), deg(v)) so both membership rows fit the bucket
     width. ``use_pallas=None`` auto-selects: the Pallas intersect kernel on
@@ -341,23 +435,18 @@ def bucketed_edge_value(
     from the plan *before* any bucket runs, so a mostly-filtered batch does
     a fraction of the unfiltered work.
     """
-    shape = jnp.shape(u)
-    un = obs.fetch(u, np.int64).reshape(-1)
-    vn = obs.fetch(v, np.int64).reshape(-1)
-    B = un.size
-    if B == 0:
-        return jnp.zeros(shape, jnp.float32)
+    out = np.zeros(un.size, np.float32)
     if node_filter is not None:
         nf = obs.fetch(node_filter, bool)
         keep = nf[np.clip(vn, 0, nf.size - 1)]
-        out = jnp.zeros((B,), jnp.float32)
         if keep.any():
-            sub = bucketed_edge_value(
+            out[keep] = edge_value_host(
                 layer, un[keep], vn[keep],
                 widths=widths, use_pallas=use_pallas, interpret=interpret,
             )
-            out = out.at[jnp.asarray(np.nonzero(keep)[0])].set(sub)
-        return out.reshape(shape)
+        return out
+    if un.size == 0:
+        return out
     with obs.span("threadle.dispatch.plan"):
         memb_ov = getattr(layer, "memb_ov", None)
         deg = np.maximum(
@@ -365,7 +454,7 @@ def bucketed_edge_value(
             _host_degrees(layer.memb, vn, memb_ov),
         )
         buckets = plan_buckets(deg, layer.max_memberships, widths)
-    out = jnp.zeros((B + 1,), jnp.float32)  # + the spare row of _scatter_back
+    launched = []
     for idx, w in buckets:
         n = pow2_ceil(idx.size)
         pallas_here = (
@@ -374,29 +463,27 @@ def bucketed_edge_value(
             else (_on_tpu() and w >= PALLAS_MIN_WIDTH)
         )
         with _launch("intersect" if pallas_here else None, w, n):
-            res = _edge_value_bucket(
+            launched.append((idx, _edge_value_bucket(
                 layer, _pad_rows(un[idx], n), _pad_rows(vn[idx], n),
                 width=w, use_pallas=pallas_here, interpret=interpret,
-            )
-            out = _scatter_back(out, idx, res)
-    return out[:B].reshape(shape)
+            )))
+    for idx, res in _fetch_buckets(launched):
+        out[idx] = res
+    return out
 
 
-def bucketed_check_edge(layer, u, v, **kw) -> jnp.ndarray:
-    return bucketed_edge_value(layer, u, v, **kw) > 0
-
-
-def bucketed_node_alters(
+def node_alters_host(
     layer,
-    u: jnp.ndarray,
+    un: np.ndarray,
     max_alters: int,
     *,
     node_filter=None,
     widths=DEFAULT_BUCKET_WIDTHS,
     use_pallas: bool | None = None,
     interpret: bool | None = None,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Degree-bucketed GetNodeAlters -> (int32[..., max_alters], mask).
+) -> np.ndarray:
+    """Degree-bucketed GetNodeAlters over flat host ids -> int32[B,
+    max_alters] on the host, SENTINEL-padded (the mask is ``!= SENTINEL``).
 
     First-hop width = membership-degree bucket; second-hop width = the max
     hyperedge size among the bucket's nodes, rounded up the same width
@@ -409,18 +496,11 @@ def bucketed_node_alters(
     to the filtered set (the post-filter oracle: take the unfiltered
     alters at full width, drop failing ids, then cap at ``max_alters``).
     """
-    shape = jnp.shape(u)
-    un = obs.fetch(u, np.int64).reshape(-1)
-    B = un.size
-    if B == 0:
-        return (
-            jnp.full(shape + (max_alters,), SENTINEL, jnp.int32),
-            jnp.zeros(shape + (max_alters,), bool),
-        )
-    nf = None if node_filter is None else jnp.asarray(
-        obs.fetch(node_filter, bool)
-    )
-    vals = jnp.full((B + 1, max_alters), SENTINEL, jnp.int32)
+    out = np.full((un.size, max_alters), SENTINEL, np.int32)
+    if un.size == 0:
+        return out
+    nf = None if node_filter is None else jnp.asarray(node_filter, bool)
+    launched = []
     for idx, wm, wn in _two_hop_plan(layer, un, widths):
         n = pow2_ceil(idx.size)
         pallas_here = _union_pallas_here(use_pallas, wm * wn)
@@ -430,58 +510,96 @@ def bucketed_node_alters(
                 width_m=wm, width_n=wn, max_alters=max_alters,
                 use_pallas=pallas_here, interpret=interpret,
             )
-            vals = _scatter_back(vals, idx, va)
-    vals = vals[:B].reshape(shape + (max_alters,))
-    return vals, vals != SENTINEL
+            launched.append((idx, va))
+    for idx, va in _fetch_buckets(launched):
+        out[idx] = va
+    return out
 
 
-def bucketed_filtered_degree(
-    layer,
-    u: jnp.ndarray,
+def filtered_degree_host(
+    layers,
+    un: np.ndarray,
     node_filter,
     *,
     widths=DEFAULT_BUCKET_WIDTHS,
     use_pallas: bool | None = None,
     interpret: bool | None = None,
-) -> jnp.ndarray:
-    """Degree-bucketed filtered-alter count -> int32[...].
+) -> np.ndarray:
+    """Degree-bucketed filtered-alter counts over flat host ids, summed
+    across ``layers`` -> int32[B] on the host.
 
     One-mode: neighbors passing the filter (gather at the bucket width +
     mask-sum). Two-mode: *distinct* co-members passing the filter — each
     bucket runs the filtered alters kernel at its exact flat width
-    (wm × wn) so the count is uncapped and exact.
+    (wm × wn) so the count is uncapped and exact. Every layer's buckets
+    launch before the one fetch.
     """
-    shape = jnp.shape(u)
-    un = obs.fetch(u, np.int64).reshape(-1)
-    B = un.size
-    if B == 0:
-        return jnp.zeros(shape, jnp.int32)
-    nf = jnp.asarray(obs.fetch(node_filter, bool))
-    out = jnp.zeros((B + 1,), jnp.int32)
-    if getattr(layer, "memb", None) is None:  # one-mode
-        with obs.span("threadle.dispatch.plan"):
-            deg = _host_degrees(layer.out, un, layer.out_ov)
-            buckets = plan_buckets(deg, max(int(deg.max()), 1), widths)
-        for idx, w in buckets:
+    out = np.zeros(un.size, np.int32)
+    if un.size == 0:
+        return out
+    nf = jnp.asarray(node_filter, bool)
+    launched = []
+    for layer in layers:
+        if getattr(layer, "memb", None) is None:  # one-mode
+            with obs.span("threadle.dispatch.plan"):
+                deg = _host_degrees(layer.out, un, layer.out_ov)
+                buckets = plan_buckets(deg, max(int(deg.max()), 1), widths)
+            for idx, w in buckets:
+                n = pow2_ceil(idx.size)
+                with _launch(None, w, n):
+                    launched.append((idx, _one_mode_filtered_degree_bucket(
+                        layer, _pad_rows(un[idx], n), nf, width=w
+                    )))
+            continue
+        for idx, wm, wn in _two_hop_plan(layer, un, widths):
             n = pow2_ceil(idx.size)
-            with _launch(None, w, n):
-                res = _one_mode_filtered_degree_bucket(
-                    layer, _pad_rows(un[idx], n), nf, width=w
-                )
-                out = _scatter_back(out, idx, res)
-        return out[:B].reshape(shape)
-    for idx, wm, wn in _two_hop_plan(layer, un, widths):
-        n = pow2_ceil(idx.size)
-        pallas_here = _union_pallas_here(use_pallas, wm * wn)
-        with _launch("segmented_union" if pallas_here else None, wm * wn, n):
-            va, _ = _node_alters_bucket(
-                layer, _pad_rows(un[idx], n), nf,
-                width_m=wm, width_n=wn, max_alters=wm * wn,
-                use_pallas=pallas_here, interpret=interpret,
-            )
-            counts = jnp.sum(va != SENTINEL, axis=-1).astype(jnp.int32)
-            out = _scatter_back(out, idx, counts)
-    return out[:B].reshape(shape)
+            pallas_here = _union_pallas_here(use_pallas, wm * wn)
+            with _launch(
+                "segmented_union" if pallas_here else None, wm * wn, n
+            ):
+                launched.append((idx, _two_mode_filtered_degree_bucket(
+                    layer, _pad_rows(un[idx], n), nf,
+                    width_m=wm, width_n=wn,
+                    use_pallas=pallas_here, interpret=interpret,
+                )))
+    for idx, res in _fetch_buckets(launched):
+        out[idx] += res
+    return out
+
+
+# ---------------------------------------------------------------------------
+# Dispatchers: ids from anywhere, a jax array back
+# ---------------------------------------------------------------------------
+
+
+def bucketed_edge_value(layer, u, v, **kw) -> jnp.ndarray:
+    """:func:`edge_value_host` over ids of any kind -> f32[...] (u's shape)."""
+    un, vn = host_ids(u, v)
+    out = edge_value_host(layer, un, vn, **kw)
+    return jnp.asarray(out.reshape(jnp.shape(u)))
+
+
+def bucketed_check_edge(layer, u, v, **kw) -> jnp.ndarray:
+    return bucketed_edge_value(layer, u, v, **kw) > 0
+
+
+def bucketed_node_alters(
+    layer, u, max_alters: int, **kw
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """:func:`node_alters_host` over ids of any kind -> (int32[...,
+    max_alters], mask)."""
+    (un,) = host_ids(u)
+    vals = node_alters_host(layer, un, max_alters, **kw)
+    vals = vals.reshape(jnp.shape(u) + (max_alters,))
+    return jnp.asarray(vals), jnp.asarray(vals != SENTINEL)
+
+
+def bucketed_filtered_degree(layer, u, node_filter, **kw) -> jnp.ndarray:
+    """:func:`filtered_degree_host` of one layer over ids of any kind ->
+    int32[...]."""
+    (un,) = host_ids(u)
+    out = filtered_degree_host((layer,), un, node_filter, **kw)
+    return jnp.asarray(out.reshape(jnp.shape(u)))
 
 
 def alters_bound(layers, u, n_nodes: int) -> int:

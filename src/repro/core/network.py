@@ -16,9 +16,12 @@ from __future__ import annotations
 
 from typing import Sequence
 
+import numpy as np
+import jax
 import jax.numpy as jnp
 
-from . import dispatch
+from . import dispatch, obs
+from .csr import SENTINEL
 from .pytree import pytree_dataclass
 from .layers import LayerOneMode, LayerTwoMode
 from .nodeset import Nodeset, create_nodeset, node_filter_mask
@@ -135,7 +138,9 @@ class Network:
         Returns (int32[B, max_alters] sorted padded, mask). Two-mode layers
         contribute pseudo-projected alters; concrete query batches run
         degree-bucketed per layer (core/dispatch.py) and the cross-layer
-        merge goes through the segmented-union dispatch rule.
+        merge goes through the segmented-union dispatch rule. A single
+        two-mode layer's rows are already sorted-unique and capped, so
+        they are the answer, with no merge.
 
         ``node_filter`` (NodeSelection or bool[n_nodes]) keeps only alters
         passing an attribute predicate — the paper's "alters of u in the
@@ -144,8 +149,11 @@ class Network:
         """
         u = _as_batch(u)
         nf = node_filter_mask(node_filter, self.n_nodes)
+        layers = self._select(layer_names)
+        if len(layers) == 1 and layers[0].mode == 2:
+            return layers[0].node_alters(u, max_alters, node_filter=nf)
         parts, masks = [], []
-        for layer in self._select(layer_names):
+        for layer in layers:
             a, m = layer.node_alters(u, max_alters, node_filter=nf)
             parts.append(a)
             masks.append(m)
@@ -165,16 +173,63 @@ class Network:
         the count matching the post-filter oracle over per-layer alters.
         Note an all-True filter therefore differs from the unfiltered
         degree on two-mode layers (distinct co-members ≠ memberships).
+        Unfiltered, every layer is read in one program
+        (``dispatch.degree_sum``).
         """
         u = _as_batch(u)
         nf = node_filter_mask(node_filter, self.n_nodes)
+        if nf is None:
+            return dispatch.degree_sum(self._select(layer_names), u)
         total = jnp.zeros(u.shape, dtype=jnp.int32)
         for layer in self._select(layer_names):
-            if nf is None:
-                total = total + jnp.take(layer.degrees(), u, mode="clip")
-            else:
-                total = total + layer.filtered_degree(u, nf)
+            total = total + layer.filtered_degree(u, nf)
         return total
+
+    # -- the same queries answered on the host (the serve executors) ---------
+    #
+    # Host ids in, host arrays out: a two-mode layer answers through the
+    # dispatcher's host core (its buckets launch back to back, then one
+    # fetch); any other query runs as above and its result is fetched
+    # once. Concrete networks only.
+
+    def edge_value_host(
+        self, layer_name: str, u, v, node_filter=None
+    ) -> np.ndarray:
+        """``edge_value`` -> float32[B] on the host."""
+        layer = self.layer(layer_name)
+        nf = node_filter_mask(node_filter, self.n_nodes)
+        if layer.mode == 2:
+            un, vn = dispatch.host_ids(u, v)
+            return dispatch.edge_value_host(layer, un, vn, node_filter=nf)
+        return obs.fetch(self.edge_value(layer_name, u, v, node_filter=nf))
+
+    def node_alters_host(
+        self, u, max_alters: int, layer_names: Sequence[str] | None = None,
+        node_filter=None,
+    ) -> tuple[np.ndarray, np.ndarray]:
+        """``node_alters`` -> (int32[B, max_alters], mask) on the host."""
+        layers = self._select(layer_names)
+        nf = node_filter_mask(node_filter, self.n_nodes)
+        if len(layers) == 1 and layers[0].mode == 2:
+            (un,) = dispatch.host_ids(u)
+            vals = dispatch.node_alters_host(
+                layers[0], un, max_alters, node_filter=nf
+            )
+            return vals, vals != SENTINEL
+        return obs.fetch(
+            self.node_alters(u, max_alters, layer_names, node_filter=nf)
+        )
+
+    def degree_host(
+        self, u, layer_names: Sequence[str] | None = None, node_filter=None,
+    ) -> np.ndarray:
+        """``degree`` -> int32[B] on the host."""
+        layers = self._select(layer_names)
+        nf = node_filter_mask(node_filter, self.n_nodes)
+        (un,) = dispatch.host_ids(u)
+        if nf is None:
+            return obs.fetch(dispatch.degree_sum(layers, un.astype(np.int32)))
+        return dispatch.filtered_degree_host(layers, un, nf)
 
     # -- batched traversal (core/traversal.py) -------------------------------
 
@@ -265,8 +320,14 @@ class Network:
         return self.nodeset.nbytes + sum(l.nbytes for l in self.layers)
 
 
-def _as_batch(x) -> jnp.ndarray:
-    x = jnp.asarray(x, dtype=jnp.int32)
+def _as_batch(x):
+    """Query ids as an int32 batch of at least one dimension. Device arrays
+    and tracers stay jax arrays; host ids stay on the host, where the
+    dispatcher plans from them without a fetch."""
+    if isinstance(x, jax.Array):
+        x = jnp.asarray(x, dtype=jnp.int32)
+    else:
+        x = np.asarray(x, dtype=np.int32)
     return x[None] if x.ndim == 0 else x
 
 

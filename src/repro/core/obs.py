@@ -16,7 +16,8 @@ never single node ids.
 
 ``fetch(x)`` is ``np.asarray(x)`` for the serving path: a device array's
 copy to the host, and the wait for the device that precedes it, is timed
-as the span ``threadle.dispatch.fetch``.
+as the span ``threadle.dispatch.fetch``. A list or tuple comes over in
+one ``jax.device_get``, under one span.
 """
 
 from __future__ import annotations
@@ -88,9 +89,15 @@ def count(name: str, n: int = 1) -> None:
         _counters[name] = _counters.get(name, 0) + int(n)
 
 
-def fetch(x, dtype=None) -> np.ndarray:
+def fetch(x, dtype=None):
     """``np.asarray(x, dtype)``, timed and counted where ``x`` is a
-    device array."""
+    device array; a list or tuple -> a list of host arrays, all copied
+    in one ``jax.device_get``."""
+    if isinstance(x, (list, tuple)):
+        if not any(isinstance(a, jax.Array) for a in x):
+            return [np.asarray(a, dtype) for a in x]
+        with span(FETCH):
+            return [np.asarray(a, dtype) for a in jax.device_get(list(x))]
     if not isinstance(x, jax.Array):
         return np.asarray(x, dtype)
     with span(FETCH):

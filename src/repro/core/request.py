@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 
 from . import dispatch, obs
+from .network import Network
 from .nodeset import node_filter_mask
 
 __all__ = [
@@ -468,15 +469,18 @@ def canonical_request(
 # Executors speak the shared query protocol (``net.edge_value`` /
 # ``node_alters`` / ``degree`` / ``khop``), so ``net`` may be a Network
 # OR a core.sharded.ShardedNetwork — the serve engine swaps the target
-# in without the executors changing. Walk fleets are the exception:
-# the scan's RNG couples the whole batch, so they always run on the
-# resident single-device replica (``net.source`` when sharded).
+# in without the executors changing. A Network answers the point kinds
+# through its host forms (``edge_value_host`` ...): a group's ids and
+# results stay on the host, and the device sees the bucket programs and
+# one fetch. Walk fleets are the exception: the scan's RNG couples the
+# whole batch, so they always run on the resident single-device replica
+# (``net.source`` when sharded).
 
 
-def _pow2_batch(ids: list) -> jnp.ndarray:
-    """A group's ids padded to a power-of-two length; executors read back
-    only the first ``len(ids)`` answers."""
-    return jnp.asarray(dispatch.pow2_pad(ids), jnp.int32)
+def _pow2_batch(ids: list) -> np.ndarray:
+    """A group's ids padded to a power-of-two length, on the host;
+    executors read back only the first ``len(ids)`` answers."""
+    return dispatch.pow2_pad(np.asarray(ids, np.int32))
 
 
 def _exec_getedge(net, group_key, creqs):
@@ -484,26 +488,36 @@ def _exec_getedge(net, group_key, creqs):
     u = _pow2_batch([c.ids[0] for c in creqs])
     v = _pow2_batch([c.ids2[0] for c in creqs])
     nf = creqs[0].mask
-    vals = obs.fetch(net.edge_value(layer_name, u, v, node_filter=nf))
+    if isinstance(net, Network):
+        vals = net.edge_value_host(layer_name, u, v, node_filter=nf)
+    else:
+        vals = obs.fetch(net.edge_value(layer_name, u, v, node_filter=nf))
     return [float(vals[i]) for i in range(len(creqs))]
 
 
 def _exec_alters(net, group_key, creqs):
     _, layers, max_alters, _ = group_key
     u = _pow2_batch([c.ids[0] for c in creqs])
-    vals, mask = net.node_alters(
-        u, max_alters, layers, node_filter=creqs[0].mask
-    )
-    vals, mask = obs.fetch(vals), obs.fetch(mask)
+    nf = creqs[0].mask
+    if isinstance(net, Network):
+        vals, mask = net.node_alters_host(
+            u, max_alters, layers, node_filter=nf
+        )
+    else:
+        vals, mask = obs.fetch(
+            net.node_alters(u, max_alters, layers, node_filter=nf)
+        )
     return [vals[i][mask[i]] for i in range(len(creqs))]
 
 
 def _exec_degree(net, group_key, creqs):
     _, layers, _ = group_key
-    flat = [i for c in creqs for i in c.ids]
-    out = obs.fetch(net.degree(
-        _pow2_batch(flat), layers, node_filter=creqs[0].mask
-    ))
+    u = _pow2_batch([i for c in creqs for i in c.ids])
+    nf = creqs[0].mask
+    if isinstance(net, Network):
+        out = net.degree_host(u, layers, node_filter=nf)
+    else:
+        out = obs.fetch(net.degree(u, layers, node_filter=nf))
     res, lo = [], 0
     for c in creqs:
         hi = lo + len(c.ids)
@@ -518,7 +532,7 @@ def _exec_khop(net, group_key, creqs):
     _, layers, k, mf, _ = group_key
     flat = [s for c in creqs for s in c.ids]
     nodes, mask, hops = net.khop(
-        _pow2_batch(flat), k, max_frontier=mf,
+        jnp.asarray(_pow2_batch(flat)), k, max_frontier=mf,
         layer_names=layers, node_filter=creqs[0].mask,
     )
     records = khop_records(

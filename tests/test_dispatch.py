@@ -1,16 +1,26 @@
 """Degree-bucketed dispatch parity: bucketed Pallas/jnp query paths must be
 bit-identical to the global-max padded reference paths AND agree with the
 materialized ``project_two_mode`` oracle — including hub nodes, empty rows,
-size-1 hyperedges, and all-sentinel batches."""
+size-1 hyperedges, and all-sentinel batches. The host forms the serve
+executors call (host ids in, host arrays out) must equal both the padded
+reference and the device-ids path."""
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.core import project_two_mode, two_mode_from_memberships
-from repro.core import dispatch
+from repro.core import (
+    add_edges,
+    create_network,
+    delete_edges,
+    one_mode_from_edges,
+    project_two_mode,
+    two_mode_from_memberships,
+)
+from repro.core import dispatch, obs
 from repro.core.csr import SENTINEL
+from repro.core.layers import has_overlay
 from repro.kernels import ops, ref
 
 
@@ -283,3 +293,115 @@ def test_node_width_cache_hit_promotes_hot_layer():
     dispatch.node_max_hyperedge_size(tiny_layer(2000))
     assert dispatch.node_max_hyperedge_size(hot) is hot_table
     dispatch._NODE_WIDTH_CACHE.clear()
+
+
+# ---------------------------------------------------------------------------
+# host ids -> host results (the serve executors' path)
+# ---------------------------------------------------------------------------
+
+
+def _parity_net(dirty: bool):
+    """A skewed two-mode layer "tm" (hub node 0) and a one-mode layer "om"
+    (hub node 0 with 150 neighbours) over 300 nodes; ``dirty`` routes
+    inserts and deletes into live delta overlays, hub rows included."""
+    n = 300
+    tm = _skewed_layer()
+    rng = np.random.default_rng(11)
+    src = np.concatenate([rng.integers(0, n - 20, 700), np.zeros(150, int)])
+    dst = np.concatenate([rng.integers(0, n - 20, 700),
+                          np.arange(1, 151)])
+    om = one_mode_from_edges(n, src, dst)
+    if dirty:
+        tm = add_edges(tm, [0, 0, 5, 7, 260], [41, 44, 0, 44, 44],
+                       compact_ratio=None)
+        tm = delete_edges(tm, [1, 2], [int(tm.memb.indices[0]), 0],
+                          compact_ratio=None)
+        om = add_edges(om, [0, 3, 4], [160, 9, 270], compact_ratio=None)
+        om = delete_edges(om, [0], [1], compact_ratio=None)
+        assert has_overlay(tm) and has_overlay(om)
+    return create_network(n).with_layer("tm", tm).with_layer("om", om)
+
+
+@pytest.fixture(scope="module", params=[False, True], ids=["clean", "dirty"])
+def parity_net(request):
+    return _parity_net(request.param)
+
+
+_B, _MAX_ALTERS = 37, 64  # a batch that is no power of two; hub rows cap
+
+
+def _query(net, kind, layer, u, v, nf, *, host):
+    """One query of ``kind`` on ``layer`` -> a tuple of its outputs: the
+    host form (host results) or the public form (jax results)."""
+    if kind == "getedge":
+        f = net.edge_value_host if host else net.edge_value
+        return (f(layer, u, v, node_filter=nf),)
+    if kind == "alters":
+        f = net.node_alters_host if host else net.node_alters
+        return tuple(f(u, _MAX_ALTERS, [layer], node_filter=nf))
+    f = net.degree_host if host else net.degree
+    return (f(u, [layer], node_filter=nf),)
+
+
+def _counter(name):
+    return obs.snapshot()["counters"].get(name, 0)
+
+
+@pytest.mark.parametrize("filtered", [False, True], ids=["all", "filter"])
+@pytest.mark.parametrize("layer", ["tm", "om"])
+@pytest.mark.parametrize("kind", ["getedge", "alters", "degree"])
+def test_host_ids_path_matches_padded_and_device_ids(
+    parity_net, kind, layer, filtered
+):
+    net = parity_net
+    rng = np.random.default_rng(12)
+    u = rng.integers(0, net.n_nodes, _B).astype(np.int32)
+    v = rng.integers(0, net.n_nodes, _B).astype(np.int32)
+    u[:3] = [0, 0, net.n_nodes - 1]  # hub, hub, isolated
+    v[:3] = [1, net.n_nodes - 1, 0]
+    nf = rng.random(net.n_nodes) < 0.5 if filtered else None
+
+    d0 = _counter("dispatch.device_ids")
+    got = _query(net, kind, layer, u, v, nf, host=True)
+    assert _counter("dispatch.device_ids") == d0
+    dev = _query(net, kind, layer, jnp.asarray(u), jnp.asarray(v), nf,
+                 host=False)
+    if kind == "degree" and not filtered:  # the whole degree vector, read
+        padded = (jnp.take(net.layer(layer).degrees(), u, mode="clip"),)
+    else:  # a traced network takes the global-max padded paths
+        padded = jax.jit(
+            lambda n, a, b: _query(n, kind, layer, a, b, nf, host=False)
+        )(net, jnp.asarray(u), jnp.asarray(v))
+    for want in (dev, padded):
+        assert len(got) == len(want)
+        for g, w in zip(got, want):
+            assert isinstance(g, np.ndarray)
+            assert g.dtype == np.asarray(w).dtype
+            np.testing.assert_array_equal(g, np.asarray(w))
+
+
+def test_single_layer_alters_without_the_merge_equals_the_merge(parity_net):
+    net = parity_net
+    layer = net.layer("tm")
+    u = np.arange(0, net.n_nodes, 9, dtype=np.int32)
+    h0 = _counter("dispatch.host_ids")
+    vals, mask = net.node_alters_host(u, _MAX_ALTERS, ["tm"])
+    assert _counter("dispatch.host_ids") == h0 + 1
+    merged = dispatch.union_rows(
+        *layer.node_alters(jnp.asarray(u), _MAX_ALTERS), _MAX_ALTERS
+    )
+    public = net.node_alters(u, _MAX_ALTERS, ["tm"])
+    for want in (merged, public):
+        np.testing.assert_array_equal(vals, np.asarray(want[0]))
+        np.testing.assert_array_equal(mask, np.asarray(want[1]))
+
+
+def test_degree_sum_reads_every_layer_in_one_program(parity_net):
+    net = parity_net
+    u = np.array([0, 5, net.n_nodes - 1, -3, net.n_nodes + 7], np.int32)
+    want = sum(
+        np.take(np.asarray(l.degrees()), u, mode="clip") for l in net.layers
+    )
+    got = dispatch.degree_sum(net.layers, u)
+    assert isinstance(got, jax.Array) and got.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got), want)

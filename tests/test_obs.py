@@ -7,6 +7,9 @@
   nested on the host plane of the profiler's ``.xplane.pb``;
 * ``kernels.<name>`` counts each launch that takes the Pallas path, not
   each trace;
+* an engine round on a ``Network`` hands its dispatchers host ids and
+  fetches once per group, while the public queries keep returning jax
+  arrays;
 * the table reaches ``GraphServeEngine.stats`` and the frontend's
   ``/stats``.
 """
@@ -24,7 +27,8 @@ import jax.numpy as jnp
 
 from repro.core import api, dispatch, obs
 from repro.core.traversal import khop_neighborhood
-from repro.serve import GraphServeClient, GraphServeEngine
+from repro.serve import GraphServeClient, GraphServeEngine, run_request
+from repro.serve import assert_results_equal
 
 
 def _spans(name):
@@ -178,6 +182,60 @@ def test_bucket_launches_are_counted(net):
     dispatch.bucketed_edge_value(layer, u, u, widths=(1,))
     assert _counter("dispatch.buckets") - b0 == n_buckets
     assert _spans("threadle.dispatch.launch")[0] - l0 == n_buckets
+
+
+# -- host ids on the served path ------------------------------------------------
+
+
+def test_engine_round_fetches_once_per_group_from_host_ids(net):
+    reqs = [
+        {"kind": "getedge", "layer": "wk", "u": 1, "v": 2},
+        {"kind": "getedge", "layer": "wk", "u": 3, "v": 40},
+        {"kind": "getedge", "layer": "wk", "u": 5, "v": 6},
+        {"kind": "alters", "u": 7, "layers": ["wk"], "max_alters": 16},
+        {"kind": "alters", "u": 8, "layers": ["wk"], "max_alters": 16},
+        {"kind": "degree", "u": 10},
+        {"kind": "degree", "u": [11, 12, 13]},
+    ]
+    eng = GraphServeEngine(net)
+    rids = [eng.submit(r) for r in reqs]
+    g0 = _spans("threadle.engine.group")[0]
+    f0 = _spans(obs.FETCH)[0]
+    h0 = _counter("dispatch.host_ids")
+    d0 = _counter("dispatch.device_ids")
+    assert eng.pump() == len(reqs)
+    groups = _spans("threadle.engine.group")[0] - g0
+    assert groups == 3
+    assert _spans(obs.FETCH)[0] - f0 == groups
+    assert _counter("dispatch.host_ids") > h0
+    assert _counter("dispatch.device_ids") == d0
+    for rid, req in zip(rids, reqs):
+        got = eng.result(rid)
+        assert got.error is None
+        assert_results_equal(got.value, run_request(net, req))
+
+
+@pytest.mark.parametrize("ids", ["int", "list", "device"])
+def test_public_queries_still_return_jax_arrays(net, ids):
+    make = {
+        "int": lambda a: int(a[0]),
+        "list": lambda a: a.tolist(),
+        "device": jnp.asarray,
+    }[ids]
+    u = np.array([1, 4, 9], np.int32)
+    v = np.array([2, 5, 8], np.int32)
+    rich = np.arange(net.n_nodes) % 2 == 0
+    out = [
+        net.edge_value("wk", make(u), make(v)),
+        net.edge_value("er", make(u), make(v)),
+        *net.node_alters(make(u), 16, ["wk"]),
+        *net.node_alters(make(u), 16),
+        net.degree(make(u)),
+        net.degree(make(u), node_filter=rich),
+    ]
+    for x in out:
+        assert isinstance(x, jax.Array)
+        assert x.shape[0] == (1 if ids == "int" else 3)
 
 
 # -- stats ----------------------------------------------------------------------

@@ -12,7 +12,8 @@ One process, data made from ``--seed``:
 3. **serve** — ``api.servenet`` starts the NDJSON/TCP frontend and
    ``GraphServeClient`` sessions over loopback send a few hundred
    ``getedge`` / ``alters`` (Workplaces, Schools), ``degree``, ``khop``
-   (k=2 on Contacts, max_frontier 256) and ``walkbatch`` requests,
+   (k=2 on Contacts, max_frontier 256, and on Workplaces, max_frontier
+   8) and ``walkbatch`` requests,
    sources drawn from the seed, hubs included;
    Kinds are served one after another, each from 8 concurrent sessions;
 4. **check** — every answer against a host numpy reference that reads
@@ -20,7 +21,9 @@ One process, data made from ``--seed``:
    through ``core/dispatch.py`` or ``kernels/``); every walk step must be
    an edge of a layer or a stay; any error record, mismatch, pump fault
    or expired deadline fails the run. Each Pallas kernel must have been
-   reached by served requests and must compile to a ``tpu_custom_call``.
+   reached by served requests and must compile to a ``tpu_custom_call``
+   (khop over the one-mode Contacts layer takes the one-pass hop; khop
+   over the two-mode Workplaces layer reaches the frontier kernel).
 
 ``--chips 4`` runs only the sharded phase: the same requests through
 ``api.servenet(net, shards=4)`` with one shard per chip, compared with
@@ -71,6 +74,7 @@ HUB_MEMBERSHIPS = 256  # Workplaces memberships per hub, at least
 CONTACT_DEGREE = 8  # mean degree of the one-mode layer
 MAX_ALTERS = 512
 KHOP_FRONTIER = 256
+KHOP_TWO_MODE_FRONTIER = 8  # Workplaces khop: the padded loop's frontier
 WALK_STARTS, WALK_WALKERS, WALK_STEPS = 4, 2, 8
 
 
@@ -183,9 +187,12 @@ class HostReference:
         frontier = [source]
         nodes, hops = [], []
         for h in range(1, k + 1):
-            cand = np.unique(np.concatenate(
-                [self.row(name, f) for f in frontier] + [np.zeros(0, np.int64)]
-            ))
+            if self.two_mode(name):
+                rows = [self.row(name, int(g), second=True)
+                        for f in frontier for g in self.row(name, f)]
+            else:
+                rows = [self.row(name, f) for f in frontier]
+            cand = np.unique(np.concatenate(rows + [np.zeros(0, np.int64)]))
             new = [int(x) for x in cand if int(x) not in visited]
             frontier = new[:max_frontier]
             if not frontier:
@@ -244,6 +251,9 @@ def make_requests(ref: HostReference, n: int, hubs: np.ndarray, seed: int,
                      "u": [node() for _ in range(int(rng.integers(1, 5)))]})
         reqs.append({"kind": "khop", "sources": node(), "k": 2,
                      "layers": ["Contacts"], "max_frontier": KHOP_FRONTIER})
+        reqs.append({"kind": "khop", "sources": node(), "k": 2,
+                     "layers": ["Workplaces"],
+                     "max_frontier": KHOP_TWO_MODE_FRONTIER})
         reqs.append({"kind": "walkbatch",
                      "starts": [node() for _ in range(WALK_STARTS)],
                      "steps": WALK_STEPS, "walkers": WALK_WALKERS,

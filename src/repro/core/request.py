@@ -263,8 +263,15 @@ def _pythonic(v):
     if isinstance(v, dict):
         return {k: _pythonic(x) for k, x in v.items()}
     if isinstance(v, (list, tuple)):
+        # a khop record's node and hop lists are thousands of plain ints:
+        # copy them whole rather than one call per element
+        if _PLAIN.issuperset(map(type, v)):
+            return list(v)
         return [_pythonic(x) for x in v]
     return v
+
+
+_PLAIN = frozenset((int, float, str, bool))
 
 
 # ---------------------------------------------------------------------------
@@ -527,17 +534,16 @@ def _exec_degree(net, group_key, creqs):
 
 
 def _exec_khop(net, group_key, creqs):
-    from .traversal import khop_records
+    from .traversal import khop_host, khop_records
 
     _, layers, k, mf, _ = group_key
     flat = [s for c in creqs for s in c.ids]
-    nodes, mask, hops = net.khop(
-        jnp.asarray(_pow2_batch(flat)), k, max_frontier=mf,
-        layer_names=layers, node_filter=creqs[0].mask,
-    )
-    records = khop_records(
-        flat, obs.fetch(nodes), obs.fetch(mask), obs.fetch(hops)
-    )
+    kw = dict(max_frontier=mf, layer_names=layers, node_filter=creqs[0].mask)
+    if isinstance(net, Network):
+        out = khop_host(net, _pow2_batch(flat), k, **kw)
+    else:
+        out = net.khop(jnp.asarray(_pow2_batch(flat)), k, **kw)
+    records = khop_records(flat, *obs.fetch(list(out)))
     res, lo = [], 0
     for c in creqs:
         hi = lo + len(c.ids)

@@ -150,8 +150,10 @@ def _segmented_union(net):
 
 
 def _frontier(net):
+    # the frontier kernel compacts the padded loop's hops, which a
+    # two-mode layer takes (one-mode hops take the one-pass program)
     khop_neighborhood(net, jnp.arange(4, dtype=jnp.int32), 1,
-                      max_frontier=32, layer_names=["er"],
+                      max_frontier=32, layer_names=["wk"],
                       use_pallas=True, interpret=True)
 
 

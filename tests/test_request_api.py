@@ -18,6 +18,7 @@ from repro.core import api
 from repro.core.cli import Session
 from repro.core.request import (
     QueryRequest,
+    QueryResult,
     canonical_request,
     merge_filter_kwargs,
     run_queries,
@@ -218,3 +219,19 @@ def test_runquery_api_entry(net):
     assert api.runquery(net, {"kind": "degree", "u": 5}) == api.runquery(
         net, QueryRequest.degree(5)
     )
+
+
+def test_result_record_copies_lists_and_converts_numpy():
+    """``to_record`` gives an independent JSON-safe copy: a khop record's
+    plain-int lists are copied whole, numpy values are converted, and a
+    list holding any numpy value is converted element by element."""
+    nodes = list(range(5000))
+    value = [{"source": 3, "count": 5000, "nodes": nodes,
+              "hops": [1] * 5000}]
+    rec = QueryResult(7, "khop", value).to_record()
+    assert rec["result"] == value
+    assert rec["result"][0]["nodes"] is not nodes
+    mixed = QueryResult(8, "alters", [np.int64(4), 2, (np.float32(0.5),),
+                                      np.arange(2)]).to_record()["result"]
+    assert mixed == [4, 2, [0.5], [0, 1]]
+    assert json.loads(json.dumps(mixed)) == mixed

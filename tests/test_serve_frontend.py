@@ -6,6 +6,7 @@ endpoints (NDJSON ops + plain HTTP probes), and the CLI surface."""
 import json
 import socket
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -115,6 +116,12 @@ def test_multiple_sessions_share_one_engine(net):
                for u in range(10)]
         for vals in results.values():
             assert vals == [int(r) for r in ref]
+        # a client's close returns before the server's handler thread has
+        # closed its session: wait for the teardown, within a bound
+        deadline = time.monotonic() + 10.0
+        while (fe.stats["sessions"]["active"]
+               and time.monotonic() < deadline):
+            time.sleep(0.01)
         st = fe.stats
         assert st["sessions"]["opened"] >= 6
         assert st["sessions"]["active"] == 0  # all disconnected

@@ -117,3 +117,27 @@ def test_dispatch_hub_bucket_compiles(hub_layer_shapes, ids):
         use_pallas=True, interpret=False,
     )
     assert _holds_kernel(lowered)
+
+
+def test_one_pass_hop_compiles_at_the_kron22_shapes(one_chip, ids):
+    """The one-pass khop hop at ``kron22.khop``'s shapes (a scale-22 graph
+    of 128M stored ids, 32 rows, k 2, max_frontier 4096, the default
+    chunk): one program, whose scratch does not grow with the hop."""
+    from repro.core import traversal
+    from repro.core.csr import CSR
+    from repro.core.layers import LayerOneMode
+
+    n, nnz = 1 << 22, 128_309_766
+    csr = CSR(
+        indptr=ids(n + 1), indices=ids(nnz), values=None, n_rows=n, n_cols=n,
+    )
+    layer = LayerOneMode(
+        out=csr, in_=None, directed=False, valued=False, allow_self=False,
+        store_inbound=True,
+    )
+    rows, k, mf = traversal.HOP_ROW_FLOOR, 2, 4096
+    compiled = traversal._hop_expand.lower(
+        (layer,), None, ids(rows), ids(k, rows * mf), ids(), ids(rows * mf),
+        chunk=traversal.HOP_CHUNK, id_bits=22,
+    ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20

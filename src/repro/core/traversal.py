@@ -258,17 +258,15 @@ def _hop_of_slot(k: int, max_frontier: int) -> np.ndarray:
 # A hop's real candidates are the neighbours of every row's frontier
 # nodes, each node's sorted row read only as far as the answer can lie
 # (see ``_one_pass_rows``): T, the sum of those lengths, which the host
-# reads from indptr. The hop is one jitted program (``_hop_expand``) that walks the T
-# candidates in chunks of HOP_CHUNK slots on the device. Each frontier
-# node is a segment of slots; slot t reads ``indices[indptr[u] + j]`` for
-# the segment (u, j) that holds it, whose start and row reach the slot
-# as a running sum of the changes added at the segments' first slots.
-# The chunk's candidates are then sorted, as keys (row, id, flag), with
-# the rows' visited ids (flag 0) and the hop's smallest ids so far (flag
-# 1), so that first occurrences not yet visited can be counted per row
-# and the ``max_frontier`` smallest kept. On the TPU a gather reads tens
-# of millions of ids a second and a sort of 2**19 keys takes a fraction
-# of a millisecond, so the program gathers only the neighbour ids. One
+# reads from indptr. The hop is one jitted program (``_hop_expand``) that
+# walks the T candidates in chunks of HOP_CHUNK slots on the device. Each
+# frontier node's run of neighbour ids is a segment of slots; the host
+# plans the live segments' slot offsets, source starts and rows as a
+# table, and the segment-copy kernel (kernels/segment_copy.py) copies each
+# run into its slots. The chunk's candidates are then sorted, as keys
+# (row, id, flag), with the rows' visited ids (flag 0) and the hop's
+# smallest ids so far (flag 1), so that first occurrences not yet visited
+# can be counted per row and the ``max_frontier`` smallest kept. One
 # chunk size means one compiled program for every hop size (the TPU's
 # sorts and running sums compile for seconds per shape), a fixed peak of
 # device memory, and fewer than HOP_CHUNK padded slots per hop.
@@ -282,7 +280,7 @@ HOP_ROW_FLOOR = 32
 _KEY_BITS = 31
 
 for _name in ("traversal.hops", "traversal.cand", "traversal.slots",
-              "traversal.launches"):
+              "traversal.launches", "traversal.copies"):
     obs.count(_name, 0)
 
 
@@ -315,15 +313,17 @@ def _degree_parts(layers) -> list[tuple]:
     return parts
 
 
-def _part_degrees(indptr, n_rows, dirty, take_dirty, rows) -> np.ndarray:
-    """Row lengths of one part at ``rows`` on the host (0 where the row is
-    the other part's), clipped as ``overlay.eff_host_degrees`` clips."""
+def _part_runs(indptr, n_rows, dirty, take_dirty, rows):
+    """(start, length) of each of ``rows``' runs in one part on the host
+    (length 0 where the row is the other part's), clipped as
+    ``overlay.eff_host_degrees`` clips."""
     r = np.clip(rows, 0, max(n_rows - 1, 0))
-    deg = indptr[r + 1] - indptr[r]
+    start = indptr[r]
+    deg = indptr[r + 1] - start
     if dirty is not None:
         d = dirty[np.clip(rows, 0, dirty.shape[0] - 1)]
         deg = np.where(d if take_dirty else ~d, deg, 0)
-    return deg
+    return start, deg
 
 
 def _prefix(x: jnp.ndarray, width: int = 128) -> jnp.ndarray:
@@ -348,14 +348,21 @@ def _shifted(x: jnp.ndarray, first) -> jnp.ndarray:
     return jnp.concatenate([jnp.full(1, first, x.dtype), x[:-1]])
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "id_bits"))
-def _hop_expand(layers, nf, src, groups, hop, ends, *, chunk, id_bits):
+@functools.partial(
+    jax.jit, static_argnames=("chunk", "id_bits", "interpret")
+)
+def _hop_expand(layers, nf, src, groups, hop, ends, runs, *, chunk,
+                id_bits, interpret=None):
     """Hop ``hop`` of the rows ``src`` int32[R] (SENTINEL rows are
     padding). ``groups`` int32[k, R * mf] holds each earlier hop's kept
     ids as sorted keys ``row << (id_bits + 1) | id << 1`` (SENTINEL
-    pads); ``ends`` int32[parts * R * mf] is the running sum of the
-    frontier's degrees, part-major, as the host planned it. Returns
-    (groups with group ``hop`` filled, that group)."""
+    pads); ``ends`` int32[parts * R * mf] is the running sum of the live
+    segments' lengths, part-major, then the total repeated, and ``runs``
+    int32[2, parts * R * mf] their source starts and tags (row * parts +
+    part), as the host planned them. Returns (groups with group ``hop``
+    filled, that group)."""
+    from repro.kernels import ops as kops
+
     R = src.shape[0]
     k, width = groups.shape
     mf = width // R
@@ -364,35 +371,8 @@ def _hop_expand(layers, nf, src, groups, hop, ends, *, chunk, id_bits):
         src != SENTINEL, (jnp.arange(R, dtype=jnp.int32) << shift)
         | (src << 1), SENTINEL,
     )
-    front = jnp.where(
-        hop == 1,
-        jnp.full(width, SENTINEL, jnp.int32).at[:R].set(src_keys),
-        jax.lax.dynamic_index_in_dim(
-            groups, jnp.maximum(hop - 2, 0), keepdims=False
-        ),
-    )
-    f_id = (front >> 1) & ((1 << id_bits) - 1)
-    f_row = jnp.minimum(front >> shift, R)
-
-    # per segment, part-major: where its neighbours start less its first
-    # slot (in 16-bit halves), and its row and part. Added up at the
-    # segments' first slots, the differences between consecutive
-    # segments' values run up to each slot's segment's value; every
-    # running sum is a difference of two values, so float32 holds it
-    parts = _degree_parts(layers)
-    n_parts = len(parts)
-    deg = ends - _shifted(ends, 0)
-    offs = ends - deg
+    sources = tuple(csr.indices for csr, _, _ in _degree_parts(layers))
     total = ends[-1]
-    base = jnp.concatenate([
-        csr.indptr[jnp.clip(f_id, 0, csr.n_rows - 1)].astype(jnp.int32)
-        for csr, _, _ in parts
-    ]) - offs
-    tag = jnp.concatenate([f_row * n_parts + i for i in range(n_parts)])
-    deltas = jnp.stack(
-        [v - _shifted(v, 0) for v in (base >> 16, base & 0xFFFF, tag)]
-    )
-    slots = jnp.arange(chunk, dtype=jnp.int32)
 
     # the source and the earlier hops' ids are visited (flag 0)
     g = jnp.arange(k, dtype=jnp.int32)[:, None]
@@ -401,24 +381,14 @@ def _hop_expand(layers, nf, src, groups, hop, ends, *, chunk, id_bits):
     ])
 
     def chunk_of(lo, best):
-        at = jnp.maximum(offs - lo, 0)  # earlier segments at the first slot
-        hi, lo16, tag = (
-            _prefix(jnp.zeros(chunk, jnp.int32).at[at].add(d, mode="drop"))
-            for d in deltas
+        cand, row = kops.segment_copy(
+            sources, ends, runs[0], runs[1], lo, chunk=chunk,
+            interpret=interpret,
         )
-        t = lo + slots
-        pos = (hi << 16) + lo16 + t
-        cand = None
-        for i, (csr, _, _) in enumerate(parts):
-            got = jnp.take(csr.indices, pos, mode="clip").astype(jnp.int32)
-            cand = got if cand is None \
-                else jnp.where(tag % n_parts == i, got, cand)
-        ok = t < total
+        ok = cand != SENTINEL
         if nf is not None:
             ok = ok & jnp.take(nf, cand, mode="clip")
-        cand_keys = jnp.where(
-            ok, ((tag // n_parts) << shift) | (cand << 1) | 1, SENTINEL
-        )
+        cand_keys = jnp.where(ok, (row << shift) | (cand << 1) | 1, SENTINEL)
 
         # candidates (flag 1) after the visited ids (flag 0); the first
         # of a (row, id) that is a candidate is new to the row; then the
@@ -481,6 +451,8 @@ def _most_rows(id_bits: int) -> int:
 def _one_pass_rows(net, layers, uniq, k, mf, nf) -> np.ndarray:
     """The one-pass k-hop of distinct sources ``uniq`` -> host int32[len,
     1 + k * mf] (the ``nodes`` layout)."""
+    from repro.kernels.segment_copy import BLOCK
+
     id_bits = _id_bits(net)
     parts = _degree_parts(layers)
     # plan inputs, as ``overlay.eff_host_degrees`` reads them
@@ -489,9 +461,9 @@ def _one_pass_rows(net, layers, uniq, k, mf, nf) -> np.ndarray:
          None if dirty is None else np.asarray(dirty), take)
         for csr, dirty, take in parts
     ]
-    # small layers take a smaller chunk
+    # small layers take a smaller chunk, of whole segment-copy blocks
     nnz = sum(int(csr.indices.shape[0]) for csr, _, _ in parts)
-    chunk = dispatch.pow2_ceil(min(nnz, HOP_CHUNK), floor=8)
+    chunk = dispatch.pow2_ceil(min(nnz, HOP_CHUNK), floor=BLOCK)
     R = min(dispatch.pow2_ceil(
         uniq.size, floor=max(min(HOP_ROW_FLOOR, chunk // mf), 1)
     ), _most_rows(id_bits))
@@ -506,6 +478,7 @@ def _one_pass_rows(net, layers, uniq, k, mf, nf) -> np.ndarray:
     groups = _empty_groups(k, R * mf) if k else None
     nf_dev = None if nf is None else jnp.asarray(nf, bool)
     shift = id_bits + 1
+    n_parts = len(parts)
     front = np.full(R * mf, SENTINEL, np.int32)
     front[:R] = rows
     front_row = np.arange(R * mf) % R
@@ -518,28 +491,44 @@ def _one_pass_rows(net, layers, uniq, k, mf, nf) -> np.ndarray:
             # lies within the first mf + seen of each row it is in, so an
             # unfiltered hop reads no further
             read = mf + seen[front_row] if nf is None else np.inf
-            ends = np.cumsum(np.concatenate([
-                np.where(live, np.minimum(_part_degrees(*part, front), read),
-                         0).astype(np.int64)
-                for part in host_parts
-            ]))
-            total = int(ends[-1])
+            part_runs = [_part_runs(*part, front) for part in host_parts]
+            lens = np.concatenate([
+                np.where(live, np.minimum(deg, read), 0).astype(np.int64)
+                for _, deg in part_runs
+            ])
+            seg = np.flatnonzero(lens)
+            lens = lens[seg]
+            total = int(lens.sum())
         if total == 0:
             break
         if total >= 2**31 - 2 * chunk:
             raise ValueError(f"hop {h} has {total} candidates; the one-pass "
                              f"hop takes fewer than 2**31")
+        # the live segments, part-major: where their slots end (then the
+        # total repeated), their source starts and their tags
+        ends = np.full(R * mf * n_parts, total, np.int32)
+        ends[: seg.size] = np.cumsum(lens)
+        runs = np.zeros((2, R * mf * n_parts), np.int32)
+        runs[0, : seg.size] = np.concatenate([st for st, _ in part_runs])[seg]
+        runs[1, : seg.size] = (
+            front_row[seg % (R * mf)] * n_parts + seg // (R * mf)
+        )
         slots = -(-total // chunk) * chunk
         with obs.span("threadle.traversal.hop", hop=h, rows=R, slots=slots):
             groups, best = _hop_expand(
-                tuple(layers), nf_dev, rows, groups, h,
-                ends.astype(np.int32), chunk=chunk, id_bits=id_bits,
+                tuple(layers), nf_dev, rows, groups, h, ends, runs=runs,
+                chunk=chunk, id_bits=id_bits,
             )
             keys = obs.fetch(best, np.int32)
         obs.count("traversal.hops")
         obs.count("traversal.cand", total)
         obs.count("traversal.slots", slots)
         obs.count("traversal.launches")
+        # one window copy per segment-copy block a segment's slots touch
+        last = ends[: seg.size].astype(np.int64) - 1
+        obs.count("traversal.copies", int(
+            (last // BLOCK - (last + 1 - lens) // BLOCK + 1).sum()
+        ))
         keys = keys[keys != SENTINEL]
         row = keys >> shift
         front = np.full(R * mf, SENTINEL, np.int32)

@@ -19,6 +19,7 @@ from repro.core.csr import SENTINEL, on_tpu as _on_tpu, sorted_isin
 from . import ref
 from .frontier import frontier_kernel
 from .intersect import LANES, intersect_count_kernel
+from .segment_copy import segment_copy_kernel
 from .segmented_union import segmented_union_kernel
 from .flash_attention import flash_attention_kernel
 from .rmsnorm import rmsnorm_kernel
@@ -221,6 +222,34 @@ def pseudo_node_alters(
     flat = jnp.where(flat == u[..., None], SENTINEL, flat)  # drop ego
     return segmented_union(
         flat, max_alters, use_pallas=use_pallas, interpret=interpret
+    )
+
+
+# ---------------------------------------------------------------------------
+# segment copy (one-pass khop's candidate slots)
+# ---------------------------------------------------------------------------
+
+
+def segment_copy(
+    sources: tuple,
+    ends: jnp.ndarray,
+    starts: jnp.ndarray,
+    tags: jnp.ndarray,
+    lo,
+    *,
+    chunk: int,
+    interpret: bool | None = None,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Slots ``[lo, lo + chunk)`` of a one-pass hop -> (ids, rows)
+    int32[chunk]: each live segment (its slots end at ``ends``, a running
+    sum) copies its run of ``sources[tag % len(sources)]`` from its
+    ``starts`` entry into its slots, one DMA'd window per segment piece
+    of each 1024-slot block. Bit-identical to
+    ``ref.segment_copy_ref``'s scatters, running sums and per-slot gather.
+    """
+    return segment_copy_kernel(
+        tuple(sources), ends, starts, tags, lo, chunk=chunk,
+        interpret=_interpret(interpret),
     )
 
 

@@ -228,3 +228,37 @@ def rmsnorm_ref(
         (w.astype(jnp.float32) + 1.0) if plus_one else w.astype(jnp.float32)
     ).astype(x.dtype)
     return x * mult * scale
+
+
+def segment_copy_ref(sources, ends, starts, tags, lo, chunk: int):
+    """Oracle for the segment-copy kernel (kernels/segment_copy.py).
+
+    Slots ``[lo, lo + chunk)`` of a hop whose live segments end at
+    ``ends`` (a running sum, then the total repeated) and read
+    ``sources[tag % len(sources)]`` from ``starts`` -> (ids, rows)
+    int32[chunk], SENTINEL ids and row 0 past the total. Each segment's
+    values (source start less its first slot, and tag), less the last
+    segment's, are added at its first slot, so that a running sum gives
+    every slot its segment's values; each slot's id is then one gather
+    per part.
+    """
+    n_parts = len(sources)
+    total = ends[-1]
+    off = jnp.concatenate([jnp.zeros(1, ends.dtype), ends[:-1]])
+    at = jnp.where(off < total, jnp.maximum(off - lo, 0), chunk)
+    base, tag = (
+        jnp.cumsum(jnp.zeros(chunk, jnp.int32).at[at].add(
+            v - jnp.concatenate([jnp.zeros(1, jnp.int32), v[:-1]]),
+            mode="drop",
+        ))
+        for v in (starts - off, tags)
+    )
+    t = lo + jnp.arange(chunk, dtype=jnp.int32)
+    pos = base + t
+    ids = jnp.take(sources[0], pos, mode="clip").astype(jnp.int32)
+    for p in range(1, n_parts):
+        got = jnp.take(sources[p], pos, mode="clip").astype(jnp.int32)
+        ids = jnp.where(tag % n_parts == p, got, ids)
+    inside = t < total
+    return (jnp.where(inside, ids, SENTINEL),
+            jnp.where(inside, tag // n_parts, 0))

@@ -122,7 +122,8 @@ def test_dispatch_hub_bucket_compiles(hub_layer_shapes, ids):
 def test_one_pass_hop_compiles_at_the_kron22_shapes(one_chip, ids):
     """The one-pass khop hop at ``kron22.khop``'s shapes (a scale-22 graph
     of 128M stored ids, 32 rows, k 2, max_frontier 4096, the default
-    chunk): one program, whose scratch does not grow with the hop."""
+    chunk): one program that holds the segment-copy kernel, whose scratch
+    does not grow with the hop."""
     from repro.core import traversal
     from repro.core.csr import CSR
     from repro.core.layers import LayerOneMode
@@ -138,6 +139,8 @@ def test_one_pass_hop_compiles_at_the_kron22_shapes(one_chip, ids):
     rows, k, mf = traversal.HOP_ROW_FLOOR, 2, 4096
     compiled = traversal._hop_expand.lower(
         (layer,), None, ids(rows), ids(k, rows * mf), ids(), ids(rows * mf),
-        chunk=traversal.HOP_CHUNK, id_bits=22,
+        ids(2, rows * mf), chunk=traversal.HOP_CHUNK, id_bits=22,
+        interpret=False,
     ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
